@@ -1,0 +1,9 @@
+"""``direct_conv3d``'s share of its roofline: Σ over its calls of
+max(ops / peak FLOP/s, bytes / peak B/s), over the calls' device time."""
+
+from bench import counts
+from bench.metrics_common import roofline
+
+
+def read(run):
+    return roofline(run, "direct_conv3d", counts.direct_conv3d)
