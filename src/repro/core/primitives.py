@@ -454,6 +454,10 @@ def apply_prepared_range(
     inverse-window crop folded into the pool, ReLU after the pool) instead
     of two separate primitive applies — numerically equivalent, fewer
     materialized intermediates.
+
+    Each layer traces under ``jax.named_scope("L<index>.<prim>")`` (a
+    fused pair under ``L<index>.<prim>_mpf``), so the device ops of a
+    profiler trace name the layer that issued them.
     """
     last_conv = max(i for i, l in enumerate(net.layers) if l.kind == "conv")
     if states is None:
@@ -476,16 +480,18 @@ def apply_prepared_range(
             and nxt.prim == "mpf"
             and nxt.index == pl.index + 1
         ):
-            x = fft_conv_pool_fused(
-                x, st["W"], st["b"],
-                fft_shape=pl.fft_shape, k=pl.kernel_size, p=nxt.pool_size,
-                use_pallas=use_pallas, fprime_chunk=pl.fprime_chunk,
-            )
+            with jax.named_scope(f"L{pl.index}.{pl.prim}_mpf"):
+                x = fft_conv_pool_fused(
+                    x, st["W"], st["b"],
+                    fft_shape=pl.fft_shape, k=pl.kernel_size, p=nxt.pool_size,
+                    use_pallas=use_pallas, fprime_chunk=pl.fprime_chunk,
+                )
             i += 2
             continue
-        x = _resolve(pl).apply(pl, x, st, use_pallas=use_pallas)
-        if pl.kind == "conv" and pl.index != last_conv:
-            x = jax.nn.relu(x)
+        with jax.named_scope(f"L{pl.index}.{pl.prim}"):
+            x = _resolve(pl).apply(pl, x, st, use_pallas=use_pallas)
+            if pl.kind == "conv" and pl.index != last_conv:
+                x = jax.nn.relu(x)
         i += 1
     return x
 

@@ -21,9 +21,19 @@ fused per-batch jit step — keyed on the device-resident volume shape —
 does not retrace for every distinct request size, and every patch start
 is core-aligned (no shifted edge patches, maximum cross-patch reuse).
 Outputs are written only over the true dense range, so bucketing is exact
-(the pad-and-crop argument in ``volume/tiler.py``).  Watch
-``executor.last_stats["retraces"]`` to see the distinct jit
-specializations stay flat as differently-sized requests stream through.
+(the pad-and-crop argument in ``volume/tiler.py``).
+
+What an operator watches: each tick refreshes ``executor.last_stats`` —
+``retraces`` (distinct jit specializations; flat as differently-sized
+requests stream through), ``peak_device_bytes``, and the exact reuse
+counters ``os_seg_fft``, ``os_seg_hits``, ``os_mad_segments``,
+``deep_strip_patches``, ``deep_full_patches`` (cumulative, under the names
+an offline ``PlanExecutor.run`` reports per sweep).  The tick's host work
+is timed by ``repro.obs`` spans (``engine.submit``, ``engine.tick``,
+``engine.schedule``, ``engine.write``, and the executor's own inside the
+tick); ``engine.queued`` marks each request's time from submission to the
+tick that first pops one of its patches.  ``docs/architecture.md``
+("Observing the volume service") says how to read them.
 
 Streaming completion (ISSUE 5): a dense output x-row is FINAL once every
 patch that writes it has run — with the x-major patch order that is
@@ -51,12 +61,14 @@ the one fused step.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..configs.base import ConvNetConfig
 from ..core.planner import Plan
 from ..volume.executor import PlanExecutor
@@ -107,6 +119,7 @@ class VolumeRequest:
     _plane_order: Tuple[int, ...] = field(default=(), repr=False)
     _next_plane: int = field(default=0, repr=False)
     _sweep_bytes_est: float = field(default=0.0, repr=False)
+    _queued_since: Optional[float] = field(default=None, repr=False)  # until first pop
 
 
 # -- request lifecycle helpers shared with serving.sharded_engine ----------
@@ -219,6 +232,11 @@ class VolumeEngine:
     # -- admission ----------------------------------------------------------
 
     def submit(self, req: VolumeRequest) -> None:
+        with obs.span("engine.submit", rid=req.rid):
+            self._submit(req)
+
+    def _submit(self, req: VolumeRequest) -> None:
+        req._queued_since = time.perf_counter()
         ex = self.executor
         axis = ex.sweep_axis if req.sweep_axis is None else int(req.sweep_axis)
         if axis != ex.sweep_axis and not ex._os_reuse:
@@ -320,6 +338,13 @@ class VolumeEngine:
     def step(self) -> int:
         """One fused batch over the priority-ordered patch queue; returns
         the number of real (non-padding) patches processed."""
+        if not any(r._patches for r in self.active):
+            return 0  # an idle poll records no span
+        with obs.span("engine.tick"):
+            return self._step()
+
+    def _schedule(self) -> List[Tuple[VolumeRequest, int]]:
+        """Pop this tick's (request, patch index) items."""
         items: List[Tuple[VolumeRequest, int]] = []
         deferred: List[VolumeRequest] = []
         pending_est = 0.0
@@ -346,8 +371,16 @@ class VolumeEngine:
             # the budget, admit the highest-ranked one anyway (one sweep at
             # a time always fits by construction of the estimate)
             self._pop_plane_capped(deferred[0], items)
-        if not items:
-            return 0
+        return items
+
+    def _step(self) -> int:
+        with obs.span("engine.schedule"):
+            items = self._schedule()
+        popped = time.perf_counter()
+        for req, _ in items:
+            if req._queued_since is not None:
+                obs.mark("engine.queued", req._queued_since, popped, rid=req.rid)
+                req._queued_since = None
         ex = self.executor
         # a drained-queue tail runs at the executor's bucketed batch size
         # (next power of two, or exactly len(items) if already compiled):
@@ -391,11 +424,12 @@ class VolumeEngine:
                 )
             ys = ex.run_patch_batch(xs)
         completed: List[VolumeRequest] = []
-        for (req, idx), y in zip(items, ys):
-            ex.write_core(req.out, req._tiling, req._tiling.patches[idx], y)
-            if finish_patch(req, req._tiling.patches[idx].start[0]):
-                ex.end_sweep(req._sweep)  # free boundary spectra + halos
-                completed.append(req)
+        with obs.span("engine.write"):
+            for (req, idx), y in zip(items, ys):
+                ex.write_core(req.out, req._tiling, req._tiling.patches[idx], y)
+                if finish_patch(req, req._tiling.patches[idx].start[0]):
+                    ex.end_sweep(req._sweep)  # free boundary spectra + halos
+                    completed.append(req)
         if completed:
             # one identity-keyed removal pass AFTER the write loop — the
             # old per-completion rebuild of ``self.active`` mutated the
@@ -404,10 +438,13 @@ class VolumeEngine:
             self.active = [r for r in self.active if id(r) not in gone]
             self.finished.extend(completed)
         self.ticks += 1
-        ex.last_stats["retraces"] = len(ex._trace_keys)
-        # lifetime peak across all sweeps served so far (the shared budget
-        # the scheduler defends)
-        ex.last_stats["peak_device_bytes"] = ex._ledger.peak
+        ex.last_stats.update(
+            ex.counter_stats(),
+            retraces=len(ex._trace_keys),
+            # lifetime peak across all sweeps served so far (the shared
+            # budget the scheduler defends)
+            peak_device_bytes=ex._ledger.peak,
+        )
         return len(items)
 
     def run_until_drained(self, max_ticks: int = 100_000) -> List[VolumeRequest]:

@@ -92,7 +92,15 @@ memory-model contract in docs/architecture.md).
 ``run`` returns the dense (out_ch, X-FOV+1, ...) output and records
 ``last_stats`` (patch/batch counts, wall seconds, measured vox/s including
 border waste, the planner's predicted vox/s for comparison, and the
-measured/predicted peak device bytes).
+measured/predicted peak device bytes); its seconds and vox/s time that
+offline sweep alone.  On the served path, ``counter_stats`` gives the
+reuse counters a ``VolumeEngine`` tick publishes, and ``repro.obs`` spans
+time the host work (``executor.begin_sweep``, ``executor.resolve``,
+``executor.dispatch``, ``executor.fetch``, ``executor.store``).  The jitted
+programs trace under named scopes — ``os_step``, ``os_strip_step``,
+``os_walk``, ``walk``, inside them ``L0.spectra``, ``L<i>.<prim>`` per
+layer and ``recombine`` — so each device op of a profiler trace names the
+program and the layer that issued it.
 """
 
 from __future__ import annotations
@@ -106,6 +114,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs.base import ConvNetConfig
 from ..core import overlap_save as os_mod
 from ..core.fft_conv import fft_conv_pool_fused_halo
@@ -346,7 +355,8 @@ class PlanExecutor:
         recombine = self.uses_mpf
 
         def _walk(states, xs):
-            return self.compiled.apply(xs, states=states, recombine=recombine)
+            with jax.named_scope("walk"):
+                return self.compiled.apply(xs, states=states, recombine=recombine)
 
         # one jitted walk; jax.jit specializes (retraces) per patch-batch
         # shape, while the prepared states stay shared call arguments.
@@ -622,28 +632,29 @@ class PlanExecutor:
         per plane on demand (``_slab``), so peak device bytes scale with
         the slab, not the volume.
         """
-        spec0 = self.compiled.layers[0].os_spec
-        max_x0 = max(0, padded.shape[1] - self.extent)
-        short = max(0, max_x0 + spec0.span - padded.shape[1])
-        self._sweep_counter += 1
-        token = self._sweep_counter
-        self._sweeps[token] = {}
-        self._sweep_axes[token] = (
-            self.sweep_axis if sweep_axis is None else int(sweep_axis)
-        )
-        if self.streaming:
-            host = np.asarray(padded, np.float32)
-            if short:
-                host = np.pad(host, ((0, 0), (0, short), (0, 0), (0, 0)))
-            self._sweep_hosts[token] = host
-            self._sweep_slabs[token] = {}
-        else:
-            vol = jnp.asarray(padded)
-            if short:
-                vol = jnp.pad(vol, ((0, 0), (0, short), (0, 0), (0, 0)))
-            self._sweep_vols[token] = vol
-            self._ledger.alloc(vol.nbytes)
-        return self._sweep_counter
+        with obs.span("executor.begin_sweep", bytes=int(padded.nbytes)):
+            spec0 = self.compiled.layers[0].os_spec
+            max_x0 = max(0, padded.shape[1] - self.extent)
+            short = max(0, max_x0 + spec0.span - padded.shape[1])
+            self._sweep_counter += 1
+            token = self._sweep_counter
+            self._sweeps[token] = {}
+            self._sweep_axes[token] = (
+                self.sweep_axis if sweep_axis is None else int(sweep_axis)
+            )
+            if self.streaming:
+                host = np.asarray(padded, np.float32)
+                if short:
+                    host = np.pad(host, ((0, 0), (0, short), (0, 0), (0, 0)))
+                self._sweep_hosts[token] = host
+                self._sweep_slabs[token] = {}
+            else:
+                vol = jnp.asarray(padded)
+                if short:
+                    vol = jnp.pad(vol, ((0, 0), (0, short), (0, 0), (0, 0)))
+                self._sweep_vols[token] = vol
+                self._ledger.alloc(vol.nbytes)
+            return self._sweep_counter
 
     def end_sweep(self, token: Optional[int]) -> None:
         self._sweep_axes.pop(token, None)
@@ -808,7 +819,8 @@ class PlanExecutor:
         ``fft_conv_pool_fused_halo``: the pool layer's input is never a
         separate walk step, yet its trailing columns still reach the halo
         cache via the fused call's second output (bitwise-identical to the
-        unfused capture off the Pallas path).
+        unfused capture off the Pallas path).  Layers trace under the
+        named scopes ``apply_prepared_range`` uses.
         """
         last_conv = max(
             i for i, l in enumerate(self.net.layers) if l.kind == "conv"
@@ -817,30 +829,33 @@ class PlanExecutor:
         i = 1
         while i < len(self.net.layers):
             pl = self.compiled.layers[i]
-            if capture:
-                h = self.net.layers[i].size - 1
-                halos.append(x[:, :, -h:])
-            if self.fuse_os and i in self._fused_pairs:
-                nxt = self.compiled.layers[i + 1]
-                x, pool_halo = fft_conv_pool_fused_halo(
-                    x, states[i]["W"], states[i]["b"],
-                    fft_shape=pl.fft_shape, k=pl.kernel_size,
-                    p=nxt.pool_size, halo_cols=nxt.pool_size - 1,
-                    use_pallas=self.use_pallas,
-                    fprime_chunk=pl.fprime_chunk,
-                )
+            fused = self.fuse_os and i in self._fused_pairs
+            with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
                 if capture:
-                    halos.append(pool_halo)
-                i += 2
-                continue
-            x = resolve_primitive(pl).apply(
-                pl, x, states[i], use_pallas=self.use_pallas
-            )
-            if pl.kind == "conv" and i != last_conv:
-                x = jax.nn.relu(x)
+                    h = self.net.layers[i].size - 1
+                    halos.append(x[:, :, -h:])
+                if fused:
+                    nxt = self.compiled.layers[i + 1]
+                    x, pool_halo = fft_conv_pool_fused_halo(
+                        x, states[i]["W"], states[i]["b"],
+                        fft_shape=pl.fft_shape, k=pl.kernel_size,
+                        p=nxt.pool_size, halo_cols=nxt.pool_size - 1,
+                        use_pallas=self.use_pallas,
+                        fprime_chunk=pl.fprime_chunk,
+                    )
+                    if capture:
+                        halos.append(pool_halo)
+                    i += 2
+                    continue
+                x = resolve_primitive(pl).apply(
+                    pl, x, states[i], use_pallas=self.use_pallas
+                )
+                if pl.kind == "conv" and i != last_conv:
+                    x = jax.nn.relu(x)
             i += 1
         if self.uses_mpf:
-            x = recombine_fragments(x, list(self.compiled.mpf_pools), S)
+            with jax.named_scope("recombine"):
+                x = recombine_fragments(x, list(self.compiled.mpf_pools), S)
         return x, tuple(halos)
 
     def _os_walk(self, states, F, *, capture: bool = False):
@@ -852,16 +867,18 @@ class PlanExecutor:
         (empty halos unless ``capture``; see ``_walk_below_input``).
         """
         pl0 = self.compiled.layers[0]
-        x = os_mod.os_apply_from_spectra(
-            F, states[0]["W"], states[0]["b"], pl0.os_spec,
-            use_pallas=self.use_pallas,
-        )
         last_conv = max(
             i for i, l in enumerate(self.net.layers) if l.kind == "conv"
         )
-        if last_conv != 0:
-            x = jax.nn.relu(x)
-        return self._walk_below_input(states, x, F.shape[0], capture=capture)
+        with jax.named_scope("os_walk"):
+            with jax.named_scope("L0.overlap_save"):
+                x = os_mod.os_apply_from_spectra(
+                    F, states[0]["W"], states[0]["b"], pl0.os_spec,
+                    use_pallas=self.use_pallas,
+                )
+                if last_conv != 0:
+                    x = jax.nn.relu(x)
+            return self._walk_below_input(states, x, F.shape[0], capture=capture)
 
     def _assemble_spectra(self, Fm, parents, pattern, rows_per_patch):
         rows = [Fm[j] if p < 0 else parents[p][j] for p, j in pattern]
@@ -884,11 +901,13 @@ class PlanExecutor:
         activation cache can serve the next x-patch's strip walk.
         """
         spec0 = self.compiled.layers[0].os_spec
-        Fm = None
-        if starts is not None:
-            Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
-        F_all = self._assemble_spectra(Fm, parents, pattern, spec0.n_segments)
-        out, halos = self._os_walk(states, F_all, capture=self.deep_reuse)
+        with jax.named_scope("os_step"):
+            with jax.named_scope("L0.spectra"):
+                Fm = None
+                if starts is not None:
+                    Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
+                F_all = self._assemble_spectra(Fm, parents, pattern, spec0.n_segments)
+            out, halos = self._os_walk(states, F_all, capture=self.deep_reuse)
         return out, Fm, halos
 
     def _os_strip_step(
@@ -906,52 +925,59 @@ class PlanExecutor:
         miss spectra, and the batch's own trailing halos for the cache.
         """
         spec0 = self.compiled.layers[0].os_spec
-        Fm = None
-        if starts is not None:
-            Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
-        F = self._assemble_spectra(Fm, parents, pattern, self._q_strip)
-        S = F.shape[0]
-        x = os_mod.os_apply_tail_from_spectra(
-            F, states[0]["W"], states[0]["b"], spec0, self.core,
-            use_pallas=self.use_pallas,
-        )
         last_conv = max(
             i for i, l in enumerate(self.net.layers) if l.kind == "conv"
         )
-        if last_conv != 0:
-            x = jax.nn.relu(x)
-        new_halos = []
-        i = 1
-        while i < len(self.net.layers):
-            pl = self._strip_layers[i]
-            h, _ = self._strip_info[i]
-            x = jnp.concatenate([halos[i - 1], x], axis=2)
-            new_halos.append(x[:, :, -h:])
-            if self.fuse_os and i in self._fused_pairs:
-                # fused pair: the pool layer's input is the cached lead
-                # halo ``halos[i]`` + the conv's ReLU output — assembled
-                # INSIDE the fused call, which returns its trailing
-                # ``strip_info[i+1]`` columns as the pool-input halo
-                nxt = self._strip_layers[i + 1]
-                h_pool, _ = self._strip_info[i + 1]
-                x, pool_halo = fft_conv_pool_fused_halo(
-                    x, strip_states[i]["W"], strip_states[i]["b"],
-                    fft_shape=pl.fft_shape, k=pl.kernel_size,
-                    p=nxt.pool_size, halo_cols=h_pool, lead=halos[i],
+        with jax.named_scope("os_strip_step"):
+            with jax.named_scope("L0.spectra"):
+                Fm = None
+                if starts is not None:
+                    Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
+                F = self._assemble_spectra(Fm, parents, pattern, self._q_strip)
+            S = F.shape[0]
+            with jax.named_scope("L0.overlap_save"):
+                x = os_mod.os_apply_tail_from_spectra(
+                    F, states[0]["W"], states[0]["b"], spec0, self.core,
                     use_pallas=self.use_pallas,
-                    fprime_chunk=pl.fprime_chunk,
                 )
-                new_halos.append(pool_halo)
-                i += 2
-                continue
-            x = resolve_primitive(pl).apply(
-                pl, x, strip_states[i], use_pallas=self.use_pallas
-            )
-            if pl.kind == "conv" and i != last_conv:
-                x = jax.nn.relu(x)
-            i += 1
-        if self.uses_mpf:
-            x = recombine_fragments(x, list(self.compiled.mpf_pools), S)
+                if last_conv != 0:
+                    x = jax.nn.relu(x)
+            new_halos = []
+            i = 1
+            while i < len(self.net.layers):
+                pl = self._strip_layers[i]
+                h, _ = self._strip_info[i]
+                fused = self.fuse_os and i in self._fused_pairs
+                with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
+                    x = jnp.concatenate([halos[i - 1], x], axis=2)
+                    new_halos.append(x[:, :, -h:])
+                    if fused:
+                        # fused pair: the pool layer's input is the cached
+                        # lead halo ``halos[i]`` + the conv's ReLU output —
+                        # assembled INSIDE the fused call, which returns its
+                        # trailing ``strip_info[i+1]`` columns as the
+                        # pool-input halo
+                        nxt = self._strip_layers[i + 1]
+                        h_pool, _ = self._strip_info[i + 1]
+                        x, pool_halo = fft_conv_pool_fused_halo(
+                            x, strip_states[i]["W"], strip_states[i]["b"],
+                            fft_shape=pl.fft_shape, k=pl.kernel_size,
+                            p=nxt.pool_size, halo_cols=h_pool, lead=halos[i],
+                            use_pallas=self.use_pallas,
+                            fprime_chunk=pl.fprime_chunk,
+                        )
+                        new_halos.append(pool_halo)
+                        i += 2
+                        continue
+                    x = resolve_primitive(pl).apply(
+                        pl, x, strip_states[i], use_pallas=self.use_pallas
+                    )
+                    if pl.kind == "conv" and i != last_conv:
+                        x = jax.nn.relu(x)
+                i += 1
+            if self.uses_mpf:
+                with jax.named_scope("recombine"):
+                    x = recombine_fragments(x, list(self.compiled.mpf_pools), S)
         return x, Fm, tuple(new_halos)
 
     def _run_os_batch(self, meta) -> np.ndarray:
@@ -977,6 +1003,26 @@ class PlanExecutor:
         if len(tokens) > 1:
             return self._run_os_batch_mixed(meta)
         token = next(iter(tokens))
+        with obs.span("executor.resolve"):
+            groups, halo_cache = self._partition(token, meta)
+        outs: List[Optional[np.ndarray]] = [None] * len(meta)
+        for rows, strip in groups:
+            ys, halos = self._run_os_group(
+                token, [meta[i] for i in rows], strip
+            )
+            for j, idx in enumerate(rows):
+                outs[idx] = ys[j]
+            if self.deep_reuse:
+                self._store_halos(halo_cache, [meta[i] for i in rows], halos)
+        return np.stack(outs)
+
+    def _partition(self, token, meta):
+        """Evict what the chunk has passed; split it into groups.
+
+        Returns ``([(rows, strip)], halo_cache)``: the full-path group and
+        (under deep reuse) the strip-path group, each per x-plane when
+        streaming.
+        """
         self._sweeps.setdefault(token, {})
         halo_cache = self._halo_caches.setdefault(token, {})
         # the patch stream is x-major with non-decreasing x (tiler
@@ -1015,16 +1061,7 @@ class PlanExecutor:
                 groups.extend((by_plane[x], strip) for x in sorted(by_plane))
             else:
                 groups.append((rows, strip))
-        outs: List[Optional[np.ndarray]] = [None] * len(meta)
-        for rows, strip in groups:
-            ys, halos = self._run_os_group(
-                token, [meta[i] for i in rows], strip
-            )
-            for j, idx in enumerate(rows):
-                outs[idx] = ys[j]
-            if self.deep_reuse:
-                self._store_halos(halo_cache, [meta[i] for i in rows], halos)
-        return np.stack(outs)
+        return groups, halo_cache
 
     def _run_os_group(self, token, metas, strip: bool):
         """Resolve + run one homogeneous (full or strip) patch group.
@@ -1034,86 +1071,91 @@ class PlanExecutor:
         strip), so the strip group sees the full group's fresh
         ``_SpectrumRef``s.  Returns ``(outputs, halos)``.
         """
-        spec0 = self.compiled.layers[0].os_spec
-        cache = self._sweeps[token]
-        # the sweep scope's axis picks the state pytrees (working-frame
-        # weights + kernel spectra); the jitted step programs are shared
-        states, strip_states = self._states_for_axis(
-            self._sweep_axes.get(token, self.sweep_axis)
-        )
-        n_seg = spec0.n_segments
-        q = self._q_strip if strip else n_seg
-        misses: List[Tuple[int, int, int]] = []
-        pattern: List[Tuple[int, int]] = []
-        parents: List = []
-        parent_pos: Dict[int, int] = {}
-        for _, keys, _start in metas:
-            for key in keys[n_seg - q :] if strip else keys:
-                F = cache.get(key)
-                if F is None:
-                    # the pending marker in the cache also dedups repeated
-                    # keys within this group (bucketed tail repeats)
-                    F = _PendingMiss(len(misses))
-                    cache[key] = F
-                    misses.append(key)
-                    self._os_misses += 1
-                else:
-                    self._os_hits += 1
-                if isinstance(F, _PendingMiss):
-                    pattern.append((-1, F.idx))
-                else:
-                    pos = parent_pos.get(id(F.parent))
-                    if pos is None:
-                        pos = parent_pos[id(F.parent)] = len(parents)
-                        parents.append(F.parent)
-                    pattern.append((pos, F.idx))
-        self._os_mad_segments += len(pattern)
-        if self.use_pallas:
-            # on the Pallas path every MAD+inverse segment runs through the
-            # fused os_segment kernel (one pallas_call: MAD, DC-bin bias,
-            # inverse, crop) — same count, so predictions stay exact
-            self._os_fused_segments += len(pattern)
-        if self.fuse_os:
-            self._fused_pair_calls += len(metas) * len(self._fused_pairs)
-        if self.streaming:
-            # the group is one x-plane (plane-capped chunks / per-plane
-            # sub-groups): its segments all live in the staged slab
-            # [x0, x0 + span), so miss starts shift into slab coordinates
-            # and the fused step's volume operand keeps one constant shape
-            x0 = metas[0][2][0]
-            vol = self._slab(token, x0)
-            off = np.asarray([x0, 0, 0], np.int32)
-        else:
-            vol = self._sweep_vols[token]
-            off = np.zeros(3, np.int32)
-        starts = (
-            jnp.asarray(np.asarray(misses, np.int32) - off) if misses else None
-        )
-        if strip:
-            halos_in = tuple(
-                jnp.concatenate(
-                    [self._halo_caches[token][m[2]][pos] for m in metas], axis=0
-                )
-                for pos in range(len(self.net.layers) - 1)
+        with obs.span("executor.resolve"):
+            spec0 = self.compiled.layers[0].os_spec
+            cache = self._sweeps[token]
+            # the sweep scope's axis picks the state pytrees (working-frame
+            # weights + kernel spectra); the jitted step programs are shared
+            states, strip_states = self._states_for_axis(
+                self._sweep_axes.get(token, self.sweep_axis)
             )
+            n_seg = spec0.n_segments
+            q = self._q_strip if strip else n_seg
+            misses: List[Tuple[int, int, int]] = []
+            pattern: List[Tuple[int, int]] = []
+            parents: List = []
+            parent_pos: Dict[int, int] = {}
+            for _, keys, _start in metas:
+                for key in keys[n_seg - q :] if strip else keys:
+                    F = cache.get(key)
+                    if F is None:
+                        # the pending marker in the cache also dedups repeated
+                        # keys within this group (bucketed tail repeats)
+                        F = _PendingMiss(len(misses))
+                        cache[key] = F
+                        misses.append(key)
+                        self._os_misses += 1
+                    else:
+                        self._os_hits += 1
+                    if isinstance(F, _PendingMiss):
+                        pattern.append((-1, F.idx))
+                    else:
+                        pos = parent_pos.get(id(F.parent))
+                        if pos is None:
+                            pos = parent_pos[id(F.parent)] = len(parents)
+                            parents.append(F.parent)
+                        pattern.append((pos, F.idx))
+            self._os_mad_segments += len(pattern)
+            if self.use_pallas:
+                # on the Pallas path every MAD+inverse segment runs through the
+                # fused os_segment kernel (one pallas_call: MAD, DC-bin bias,
+                # inverse, crop) — same count, so predictions stay exact
+                self._os_fused_segments += len(pattern)
+            if self.fuse_os:
+                self._fused_pair_calls += len(metas) * len(self._fused_pairs)
+            if self.streaming:
+                # the group is one x-plane (plane-capped chunks / per-plane
+                # sub-groups): its segments all live in the staged slab
+                # [x0, x0 + span), so miss starts shift into slab coordinates
+                # and the fused step's volume operand keeps one constant shape
+                x0 = metas[0][2][0]
+                vol = self._slab(token, x0)
+                off = np.asarray([x0, 0, 0], np.int32)
+            else:
+                vol = self._sweep_vols[token]
+                off = np.zeros(3, np.int32)
+            starts = (
+                jnp.asarray(np.asarray(misses, np.int32) - off) if misses else None
+            )
+            halos_in = None
+            if strip:
+                halos_in = tuple(
+                    jnp.concatenate(
+                        [self._halo_caches[token][m[2]][pos] for m in metas], axis=0
+                    )
+                    for pos in range(len(self.net.layers) - 1)
+                )
+        if strip:
             self._record_trace(
                 ("strip", tuple(pattern), None if starts is None else len(misses),
                  vol.shape, len(parents))
             )
-            out, F_m, halos = self._jit_os_strip_step(
-                states, strip_states, vol,
-                starts, tuple(parents), halos_in, pattern=tuple(pattern),
-            )
+            with obs.span("executor.dispatch"):
+                out, F_m, halos = self._jit_os_strip_step(
+                    states, strip_states, vol,
+                    starts, tuple(parents), halos_in, pattern=tuple(pattern),
+                )
             self._deep_strips += len(metas)
         else:
             self._record_trace(
                 ("full", tuple(pattern), None if starts is None else len(misses),
                  vol.shape, len(parents))
             )
-            out, F_m, halos = self._jit_os_step(
-                states, vol,
-                starts, tuple(parents), pattern=tuple(pattern),
-            )
+            with obs.span("executor.dispatch"):
+                out, F_m, halos = self._jit_os_step(
+                    states, vol,
+                    starts, tuple(parents), pattern=tuple(pattern),
+                )
             self._deep_fulls += len(metas)
         # the ledger's transient sample: group output + miss spectra +
         # captured halos in flight on top of the resident working set
@@ -1123,7 +1165,8 @@ class PlanExecutor:
             + sum(h.nbytes for h in halos)
         )
         self._store_spectra(token, cache, misses, F_m)
-        return np.asarray(out), halos
+        with obs.span("executor.fetch"):
+            return np.asarray(out), halos
 
     def _store_spectra(self, token, cache, misses, F_m) -> None:
         """File a group's miss spectra, split by absolute segment x.
@@ -1137,19 +1180,20 @@ class PlanExecutor:
         """
         if not misses:
             return
-        by_x: Dict[int, List[int]] = {}
-        for i, key in enumerate(misses):
-            by_x.setdefault(key[0], []).append(i)
-        for _x, idxs in by_x.items():
-            if len(idxs) == len(misses):
-                parent = F_m
-            else:
-                parent = jnp.take(F_m, jnp.asarray(np.asarray(idxs, np.int32)), axis=0)
-            self._ledger.alloc(parent.nbytes)
-            share = parent.nbytes / len(idxs)
-            for j, i in enumerate(idxs):
-                cache[misses[i]] = _SpectrumRef(parent, j)
-                self._key_bytes[(token, misses[i])] = share
+        with obs.span("executor.store"):
+            by_x: Dict[int, List[int]] = {}
+            for i, key in enumerate(misses):
+                by_x.setdefault(key[0], []).append(i)
+            for _x, idxs in by_x.items():
+                if len(idxs) == len(misses):
+                    parent = F_m
+                else:
+                    parent = jnp.take(F_m, jnp.asarray(np.asarray(idxs, np.int32)), axis=0)
+                self._ledger.alloc(parent.nbytes)
+                share = parent.nbytes / len(idxs)
+                for j, i in enumerate(idxs):
+                    cache[misses[i]] = _SpectrumRef(parent, j)
+                    self._key_bytes[(token, misses[i])] = share
 
     def _store_halos(self, halo_cache, metas, halos) -> None:
         """File a group's trailing activation halos for the x-successors.
@@ -1159,110 +1203,115 @@ class PlanExecutor:
         core-aligned patches store — a shifted edge patch's coverage can
         never serve an aligned successor's coordinate frame.
         """
-        for j, (_, _, start) in enumerate(metas):
-            if start[0] % self.core:
-                continue
-            entry = []
-            for pos in range(len(self.net.layers) - 1):
-                _, frag = self._strip_info[pos + 1]
-                entry.append(halos[pos][j * frag : (j + 1) * frag])
-            key = (start[0] + self.core, start[1], start[2])
-            old = halo_cache.get(key)
-            if old is not None:
-                self._ledger.free(sum(h.nbytes for h in old))
-            halo_cache[key] = entry
-            self._ledger.alloc(sum(h.nbytes for h in entry))
+        with obs.span("executor.store"):
+            for j, (_, _, start) in enumerate(metas):
+                if start[0] % self.core:
+                    continue
+                entry = []
+                for pos in range(len(self.net.layers) - 1):
+                    _, frag = self._strip_info[pos + 1]
+                    entry.append(halos[pos][j * frag : (j + 1) * frag])
+                key = (start[0] + self.core, start[1], start[2])
+                old = halo_cache.get(key)
+                if old is not None:
+                    self._ledger.free(sum(h.nbytes for h in old))
+                halo_cache[key] = entry
+                self._ledger.alloc(sum(h.nbytes for h in entry))
 
     def _run_os_batch_mixed(self, meta) -> np.ndarray:
         """Cross-request serving batches: one batched FFT per sweep, then
         the spectra-stack walk (full path; deep reuse resumes on the next
         single-sweep tick — mixed ticks don't store halos)."""
-        spec0 = self.compiled.layers[0].os_spec
-        slots: List[List] = []  # per patch: (key, _SpectrumRef | _PendingMiss)
-        miss_keys: Dict[int, List[Tuple[int, int, int]]] = {}
-        for token, keys, start in meta:
-            cache = self._sweeps.setdefault(token, {})
-            self._evict_left_of(token, start[0])
-            per_seg = []
-            for key in keys:
-                F = cache.get(key)
-                if F is None:
-                    misses = miss_keys.setdefault(token, [])
-                    F = _PendingMiss(len(misses))
-                    cache[key] = F
-                    misses.append(key)
-                    self._os_misses += 1
+        with obs.span("executor.resolve"):
+            spec0 = self.compiled.layers[0].os_spec
+            slots: List[List] = []  # per patch: (key, _SpectrumRef | _PendingMiss)
+            miss_keys: Dict[int, List[Tuple[int, int, int]]] = {}
+            for token, keys, start in meta:
+                cache = self._sweeps.setdefault(token, {})
+                self._evict_left_of(token, start[0])
+                per_seg = []
+                for key in keys:
+                    F = cache.get(key)
+                    if F is None:
+                        misses = miss_keys.setdefault(token, [])
+                        F = _PendingMiss(len(misses))
+                        cache[key] = F
+                        misses.append(key)
+                        self._os_misses += 1
+                    else:
+                        self._os_hits += 1
+                    per_seg.append((key, F))
+                slots.append(per_seg)
+                self._os_mad_segments += spec0.n_segments
+                if self.use_pallas:
+                    self._os_fused_segments += spec0.n_segments
+                if self.fuse_os:
+                    self._fused_pair_calls += len(self._fused_pairs)
+                self._deep_fulls += 1
+            for token, keys_m in miss_keys.items():
+                # pad the miss count to a power of two so the distinct compiled
+                # FFT batch sizes stay O(log(S·n_seg))
+                M = len(keys_m)
+                Mp = 1
+                while Mp < M:
+                    Mp *= 2
+                starts = np.asarray(keys_m + [keys_m[-1]] * (Mp - M), np.int32)
+                if self.streaming:
+                    # stage a transient slab covering this token's misses; the
+                    # shape varies per tick (fallback path — the single-sweep
+                    # fused path is the one with the constant-shape guarantee)
+                    host = self._sweep_hosts[token]
+                    x_min = min(k[0] for k in keys_m)
+                    x_hi = max(k[0] for k in keys_m) + spec0.seg_extent
+                    slab = jnp.asarray(host[:, x_min:x_hi])
+                    self._ledger.transient(slab.nbytes)
+                    starts = starts - np.asarray([x_min, 0, 0], np.int32)
+                    vol = slab
                 else:
-                    self._os_hits += 1
-                per_seg.append((key, F))
-            slots.append(per_seg)
-            self._os_mad_segments += spec0.n_segments
-            if self.use_pallas:
-                self._os_fused_segments += spec0.n_segments
-            if self.fuse_os:
-                self._fused_pair_calls += len(self._fused_pairs)
-            self._deep_fulls += 1
-        for token, keys_m in miss_keys.items():
-            # pad the miss count to a power of two so the distinct compiled
-            # FFT batch sizes stay O(log(S·n_seg))
-            M = len(keys_m)
-            Mp = 1
-            while Mp < M:
-                Mp *= 2
-            starts = np.asarray(keys_m + [keys_m[-1]] * (Mp - M), np.int32)
-            if self.streaming:
-                # stage a transient slab covering this token's misses; the
-                # shape varies per tick (fallback path — the single-sweep
-                # fused path is the one with the constant-shape guarantee)
-                host = self._sweep_hosts[token]
-                x_min = min(k[0] for k in keys_m)
-                x_hi = max(k[0] for k in keys_m) + spec0.seg_extent
-                slab = jnp.asarray(host[:, x_min:x_hi])
-                self._ledger.transient(slab.nbytes)
-                starts = starts - np.asarray([x_min, 0, 0], np.int32)
-                vol = slab
-            else:
-                vol = self._sweep_vols[token]
-            F_all_miss = os_mod.segment_spectra_at(
-                vol, jnp.asarray(starts), spec0, self.extent
-            )
-            self._ledger.transient(F_all_miss.nbytes)
-            # store split by absolute segment x (same invariant as the
-            # single-sweep path): per-key eviction then frees real device
-            # buffers instead of leaving a multi-plane parent pinned by
-            # its youngest rows — the ledger stays honest in exactly the
-            # cross-request mode the shared device budget governs.  The
-            # power-of-two padding rows are dropped before storage.
-            self._store_spectra(
-                token, self._sweeps[token], keys_m, F_all_miss[:M]
-            )
-        # pass 2: materialize rows and walk.  Requests sweeping different
-        # axes need different state pytrees (working-frame weights), so the
-        # tick sub-batches per axis — one stacked walk per axis group,
-        # outputs reassembled in meta order.  Single-axis ticks (the common
-        # case) keep the one-stack walk.
-        by_axis: Dict[int, List[int]] = {}
-        for i, (token, _, _) in enumerate(meta):
-            axis = self._sweep_axes.get(token, self.sweep_axis)
-            by_axis.setdefault(axis, []).append(i)
+                    vol = self._sweep_vols[token]
+                F_all_miss = os_mod.segment_spectra_at(
+                    vol, jnp.asarray(starts), spec0, self.extent
+                )
+                self._ledger.transient(F_all_miss.nbytes)
+                # store split by absolute segment x (same invariant as the
+                # single-sweep path): per-key eviction then frees real device
+                # buffers instead of leaving a multi-plane parent pinned by
+                # its youngest rows — the ledger stays honest in exactly the
+                # cross-request mode the shared device budget governs.  The
+                # power-of-two padding rows are dropped before storage.
+                self._store_spectra(
+                    token, self._sweeps[token], keys_m, F_all_miss[:M]
+                )
+            # pass 2: materialize rows and walk.  Requests sweeping different
+            # axes need different state pytrees (working-frame weights), so the
+            # tick sub-batches per axis — one stacked walk per axis group,
+            # outputs reassembled in meta order.  Single-axis ticks (the common
+            # case) keep the one-stack walk.
+            by_axis: Dict[int, List[int]] = {}
+            for i, (token, _, _) in enumerate(meta):
+                axis = self._sweep_axes.get(token, self.sweep_axis)
+                by_axis.setdefault(axis, []).append(i)
         outs: List[Optional[np.ndarray]] = [None] * len(slots)
         for axis in sorted(by_axis):
             rows = by_axis[axis]
-            flat = []
-            for i in rows:
-                cache = self._sweeps[meta[i][0]]
-                for key, F in slots[i]:
-                    if isinstance(F, _PendingMiss):
-                        F = cache[key]  # _store_spectra filed the real ref
-                    flat.append(F.parent[F.idx])
-            F_all = jnp.stack(flat).reshape(
-                (len(rows), spec0.n_segments) + flat[0].shape
-            )  # (S_axis, n_seg, f, ña, ñb, ñc)
+            with obs.span("executor.resolve"):
+                flat = []
+                for i in rows:
+                    cache = self._sweeps[meta[i][0]]
+                    for key, F in slots[i]:
+                        if isinstance(F, _PendingMiss):
+                            F = cache[key]  # _store_spectra filed the real ref
+                        flat.append(F.parent[F.idx])
+                F_all = jnp.stack(flat).reshape(
+                    (len(rows), spec0.n_segments) + flat[0].shape
+                )  # (S_axis, n_seg, f, ña, ñb, ñc)
             self._record_trace(("oswalk", F_all.shape))
             states, _ = self._states_for_axis(axis)
-            out, _ = self._jit_os_walk(states, F_all)
+            with obs.span("executor.dispatch"):
+                out, _ = self._jit_os_walk(states, F_all)
             self._ledger.transient(F_all.nbytes + out.nbytes)
-            out = np.asarray(out)
+            with obs.span("executor.fetch"):
+                out = np.asarray(out)
             for j, i in enumerate(rows):
                 outs[i] = out[j]
         return np.stack(outs)
@@ -1312,9 +1361,11 @@ class PlanExecutor:
         states = self.compiled.states
         if self.uses_mpf:
             self._record_trace(("walk", xs.shape))
-            y = self._jit_walk(states, jnp.asarray(xs))
+            with obs.span("executor.dispatch"):
+                y = self._jit_walk(states, jnp.asarray(xs))
             self._ledger.transient(xs.nbytes + y.nbytes)
-            return np.asarray(y)
+            with obs.span("executor.fetch"):
+                return np.asarray(y)
         # baseline: all-subsamplings outer loop (P³ shifted passes)
         out = np.empty(
             (S, self.out_channels) + (self.core,) * 3, np.float32
@@ -1322,9 +1373,11 @@ class PlanExecutor:
         n = self.n_in
         for ox, oy, oz in itertools.product(range(self.P), repeat=3):
             sub = xs[:, :, ox : ox + n, oy : oy + n, oz : oz + n]
-            yd = self._jit_walk(states, jnp.asarray(sub))
+            with obs.span("executor.dispatch"):
+                yd = self._jit_walk(states, jnp.asarray(sub))
             self._ledger.transient(sub.nbytes + yd.nbytes)
-            y = np.asarray(yd)
+            with obs.span("executor.fetch"):
+                y = np.asarray(yd)
             out[:, :, ox :: self.P, oy :: self.P, oz :: self.P] = y
         return out
 
@@ -1390,16 +1443,7 @@ class PlanExecutor:
             "measured_voxps": vox / dt if dt > 0 else float("inf"),
             "predicted_voxps": self.plan.throughput if self.plan else float("nan"),
             "waste_fraction": tiling.waste_fraction,
-            # overlap-save input-spectra reuse (0/0 when not active):
-            # segment FFTs actually run vs. segments served from the cache
-            "os_seg_fft": self._os_misses,
-            "os_seg_hits": self._os_hits,
-            # sweep-aware accounting (matches predict_counts exactly):
-            # per-segment MAD+inverse passes run, and how many patches
-            # took the deep-reuse strip path vs. the full-extent path
-            "os_mad_segments": self._os_mad_segments,
-            "deep_strip_patches": self._deep_strips,
-            "deep_full_patches": self._deep_fulls,
+            **self.counter_stats(),
             # fused-epilogue accounting: conv+pool pairs the halo-emitting
             # fused epilogue served (``fuse_os``; (strips+fulls) × eligible
             # pairs), and segments run through the fused Pallas segment
@@ -1426,6 +1470,26 @@ class PlanExecutor:
             # per-patch hand-off size is chunk-size independent)
             self.last_stats.update(self._hetero_stats)
         return out
+
+    def counter_stats(self) -> Dict[str, int]:
+        """The exact reuse counters under their ``last_stats`` names.
+
+        Counted since construction or the last offline ``run`` (which
+        restarts them), so a serving engine's ticks publish lifetime
+        totals and ``run`` one sweep's.
+        """
+        return {
+            # overlap-save input-spectra reuse (0/0 when not active):
+            # segment FFTs actually run vs. segments served from the cache
+            "os_seg_fft": self._os_misses,
+            "os_seg_hits": self._os_hits,
+            # sweep-aware accounting (matches predict_counts exactly):
+            # per-segment MAD+inverse passes run, and how many patches
+            # took the deep-reuse strip path vs. the full-extent path
+            "os_mad_segments": self._os_mad_segments,
+            "deep_strip_patches": self._deep_strips,
+            "deep_full_patches": self._deep_fulls,
+        }
 
     # -- memory model --------------------------------------------------------
 

@@ -1,0 +1,229 @@
+"""The volume service's spans and served-path counters (``repro.obs``).
+
+A span nests under the one open on its own thread, its self time is its
+duration less its children's, the ring keeps the newest records and
+says when it dropped one a window needs, and a span lands in the
+profiler's own trace.  Serving one request through ``VolumeEngine``
+records only dotted span names (the benchmark's trace reduction owns
+``step``, ``submit`` and ``wait``), one ``engine.queued`` mark per
+request, and ``last_stats`` counters equal to the planner's prediction.
+"""
+
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+
+from repro import obs
+from repro.configs.base import ConvLayerSpec as L, ConvNetConfig
+from repro.core import convnet
+from repro.serving import VolumeEngine, VolumeRequest
+
+NET = ConvNetConfig(
+    "obs-toy", 1,
+    (L("conv", 3, 4), L("pool", 2), L("conv", 3, 4), L("pool", 2), L("conv", 3, 2)),
+)
+MIX = [
+    "overlap_save" if i == 0 else ("fft_cached" if l.kind == "conv" else "mpf")
+    for i, l in enumerate(NET.layers)
+]
+FOV = NET.field_of_view()
+CORE = NET.total_pooling()
+# interior-rich (8 patches, 6 on the strip path) and a 3x3x3 patch cube
+SHAPES = {
+    "interior": (4 * CORE + FOV - 1, 2 * CORE + FOV - 1, CORE + FOV - 1),
+    "cube": (3 * CORE + FOV - 1,) * 3,
+}
+SERVED = {
+    "engine.submit", "engine.tick", "engine.schedule", "engine.write", "engine.queued",
+    "executor.begin_sweep", "executor.resolve", "executor.dispatch", "executor.fetch",
+    "executor.store",
+}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return convnet.init_params(jax.random.PRNGKey(0), NET)
+
+
+def _volume(shape, seed=0):
+    return np.random.default_rng(seed).normal(size=(1,) + shape).astype(np.float32)
+
+
+def _serve(params, requests, batch=2):
+    """Serve ``requests`` [(rid, shape)] through one engine; return the
+    engine and every record made meanwhile."""
+    eng = VolumeEngine(params, NET, prims=MIX, m=1, batch=batch, tuned=None)
+    t0 = time.perf_counter()
+    for rid, shape in requests:
+        eng.submit(VolumeRequest(rid, _volume(shape, rid)))
+    eng.run_until_drained()
+    recs, dropped = obs.spans(t0)
+    assert not dropped
+    return eng, recs
+
+
+def test_nesting_parent_and_self_time():
+    rec = obs.Recorder()
+    with rec.span("a", k=1) as a:
+        time.sleep(0.01)
+        with rec.span("b") as b:
+            time.sleep(0.02)
+        with rec.span("c") as c:
+            with rec.span("d") as d:
+                time.sleep(0.01)
+    recs, dropped = rec.spans()
+    assert not dropped
+    by = {r.name: r for r in recs}
+    assert [r.name for r in recs] == ["b", "d", "c", "a"]  # in order of their end
+    assert by["a"].parent is None and by["a"].attrs == {"k": 1}
+    assert by["b"].parent == by["c"].parent == a.sid
+    assert by["d"].parent == c.sid and b.sid != d.sid
+    own = obs.self_times(recs)
+    dur = {r.name: r.t1 - r.t0 for r in recs}
+    assert own[a.sid] == pytest.approx(dur["a"] - dur["b"] - dur["c"])
+    assert own[c.sid] == pytest.approx(dur["c"] - dur["d"])
+    assert own[d.sid] == dur["d"] >= 0.01
+    assert 0.01 <= own[a.sid] < dur["a"] - 0.03
+
+
+def test_spans_nest_per_thread():
+    rec = obs.Recorder()
+    gate = threading.Barrier(2, timeout=10)
+
+    def work(tag):
+        with rec.span(f"outer.{tag}"):
+            gate.wait()  # both outer spans are open at once
+            with rec.span(f"inner.{tag}"):
+                gate.wait()
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "xy"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert not any(t.is_alive() for t in threads)
+    recs, _ = rec.spans()
+    by = {r.name: r for r in recs}
+    assert len(by) == 4
+    for tag in "xy":
+        assert by[f"outer.{tag}"].parent is None
+        assert by[f"inner.{tag}"].parent == by[f"outer.{tag}"].sid
+    with rec.span("after"):
+        pass
+    assert rec.spans()[0][-1].parent is None  # nothing left open on this thread
+
+
+def test_ring_keeps_the_newest_and_reports_drops(monkeypatch):
+    monkeypatch.setattr(obs, "RING", 4)
+    rec = obs.Recorder()
+    for i in range(6):  # [0, 1], [1, 2], ... [5, 6]: the first two fall out
+        rec.mark("m", float(i), float(i + 1), i=i)
+    recs, dropped = rec.spans()
+    assert [r.attrs["i"] for r in recs] == [2, 3, 4, 5]
+    assert dropped  # the unbounded window reaches the dropped [0, 1] and [1, 2]
+    recs, dropped = rec.spans(2.5, 4.5)
+    assert [r.attrs["i"] for r in recs] == [2, 3, 4] and not dropped
+    assert rec.spans(2.0)[1]  # [1, 2] ended at 2.0, inside the window
+    assert [r.attrs["i"] for r in rec.spans(5.5, 9.0)[0]] == [5]
+    assert not obs.Recorder().spans()[1]
+
+
+def test_a_span_lands_in_the_profilers_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    rec = obs.Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("executor.fetch", rid=5):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {e.name for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for e in line.events}
+    assert "executor.fetch" in names
+    assert [r.name for r in rec.spans()[0]] == ["executor.fetch"]
+
+
+def test_served_spans_are_dotted_and_never_the_harness_names(params):
+    _, recs = _serve(params, [(1, SHAPES["interior"])])
+    names = {r.name for r in recs}
+    assert names == SERVED
+    assert not names & {"step", "submit", "wait"}
+    ticks = {r.sid for r in recs if r.name == "engine.tick"}
+    inside = {"engine.schedule", "engine.write", "executor.resolve", "executor.dispatch",
+              "executor.fetch", "executor.store", "executor.begin_sweep"}
+    by_sid = {r.sid: r for r in recs}
+
+    def tick_of(r):
+        while r.parent is not None:
+            r = by_sid[r.parent]
+        return r.sid
+
+    # every executor and engine phase runs inside a tick, nested once
+    assert all(tick_of(r) in ticks for r in recs if r.name in inside)
+    assert all(by_sid[r.parent].name == "engine.tick" for r in recs
+               if r.name in ("engine.schedule", "engine.write", "executor.dispatch"))
+
+
+def test_queued_marks_each_request_once(params):
+    shapes = [SHAPES["interior"], SHAPES["cube"], SHAPES["interior"]]
+    eng, recs = _serve(params, list(zip((11, 12, 13), shapes)))
+    queued = [r for r in recs if r.name == "engine.queued"]
+    assert sorted(r.attrs["rid"] for r in queued) == [11, 12, 13]
+    submits = {r.attrs["rid"]: r for r in recs if r.name == "engine.submit"}
+    for r in queued:
+        assert r.parent is None
+        assert submits[r.attrs["rid"]].t0 <= r.t0 <= r.t1
+    # FIFO: requests leave the queue in the order they entered it
+    ends = [r.t1 for r in sorted(queued, key=lambda r: r.attrs["rid"])]
+    assert ends == sorted(ends)
+    assert len(eng.finished) == 3
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("shape", SHAPES.values(), ids=SHAPES.keys())
+def test_served_counters_equal_the_prediction(params, shape, batch):
+    eng, _ = _serve(params, [(1, shape)], batch=batch)
+    ex = eng.executor
+    pred = ex.predict_counts(shape)
+    stats = ex.last_stats
+    assert ex.bucket_shape(shape) == shape
+    assert (stats["os_seg_fft"], stats["os_seg_hits"], stats["os_mad_segments"],
+            stats["deep_strip_patches"], stats["deep_full_patches"]) == (
+        pred.seg_fft, pred.seg_hits, pred.mad_segments, pred.strip_patches, pred.full_patches)
+    assert 0 < pred.strip_patches < pred.n_patches
+    # the benchmark's strip_share reader still finds the private counters
+    assert (ex._deep_strips, ex._deep_fulls) == (pred.strip_patches, pred.full_patches)
+
+
+def test_programs_name_their_layers(params):
+    """The jitted steps trace under ``os_step`` / ``os_strip_step`` with a
+    ``L<i>.<prim>`` scope per layer, carried into the compiled ops."""
+    eng = VolumeEngine(params, NET, prims=MIX, m=1, batch=2, tuned=None)
+    ex = eng.executor
+    calls = {}
+    for top in ("os_step", "os_strip_step"):
+        jitted = getattr(ex, f"_jit_{top}")
+
+        def keep(*a, _top=top, _jitted=jitted, **kw):
+            calls.setdefault(_top, (_jitted, a, kw))
+            return _jitted(*a, **kw)
+
+        setattr(ex, f"_jit_{top}", keep)
+    eng.submit(VolumeRequest(1, _volume(SHAPES["interior"])))
+    eng.run_until_drained()
+    assert set(calls) == {"os_step", "os_strip_step"}
+    for top, (program, a, kw) in calls.items():
+        text = program.lower(*a, **kw).compile().as_text()
+        scopes = set(re.findall(r'op_name="jit\([^)]*\)/([^"]*)"', text))
+        walk = f"{top}/" if top == "os_strip_step" else f"{top}/os_walk/"
+        for layer in ("L0.overlap_save", "L1.mpf", "L2.fft_cached", "L3.mpf", "L4.fft_cached",
+                      "recombine"):
+            assert any(s.startswith(walk + layer) for s in scopes), (top, layer)
+        assert any(s.startswith(f"{top}/L0.spectra") for s in scopes)
