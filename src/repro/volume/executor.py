@@ -90,17 +90,17 @@ change), so streamed output is bitwise-equal to the dense path.  A
 memory-model contract in docs/architecture.md).
 
 ``run`` returns the dense (out_ch, X-FOV+1, ...) output and records
-``last_stats`` (patch/batch counts, wall seconds, measured vox/s including
-border waste, the planner's predicted vox/s for comparison, and the
-measured/predicted peak device bytes); its seconds and vox/s time that
-offline sweep alone.  On the served path, ``counter_stats`` gives the
-reuse counters a ``VolumeEngine`` tick publishes, and ``repro.obs`` spans
-time the host work (``executor.begin_sweep``, ``executor.resolve``,
-``executor.dispatch``, ``executor.fetch``, ``executor.store``).  The jitted
-programs trace under named scopes — ``os_step``, ``os_strip_step``,
-``os_walk``, ``walk``, inside them ``L0.spectra``, ``L<i>.<prim>`` per
-layer and ``recombine`` — so each device op of a profiler trace names the
-program and the layer that issued it.
+``last_stats`` (patch/batch counts, wall seconds, measured vox/s with border
+waste, the planner's predicted vox/s, measured/predicted peak device bytes);
+its seconds and vox/s time that offline sweep alone.  On the served path,
+``counter_stats`` gives the reuse counters a ``VolumeEngine`` tick publishes,
+and ``repro.obs`` spans time the host work (``executor.begin_sweep``,
+``executor.resolve``, ``executor.dispatch``, ``executor.store``,
+``executor.fetch``): a single-sweep batch dispatches every group and stores
+its spectra and halos, then one fetch blocks on the device.  The jitted
+programs trace under named scopes (``os_step``, ``os_strip_step``, ``os_walk``,
+``walk``; in them ``L0.spectra``, ``L<i>.<prim>`` and ``recombine``), so each
+device op of a profiler trace names the program and the layer that issued it.
 """
 
 from __future__ import annotations
@@ -1005,15 +1005,22 @@ class PlanExecutor:
         token = next(iter(tokens))
         with obs.span("executor.resolve"):
             groups, halo_cache = self._partition(token, meta)
-        outs: List[Optional[np.ndarray]] = [None] * len(meta)
+        # file each group's spectra and halos while the device computes, and
+        # block on it once, after the last dispatch: a later group still finds
+        # all an earlier one files before it resolves, so only the wait moves
+        pending = []
         for rows, strip in groups:
-            ys, halos = self._run_os_group(
-                token, [meta[i] for i in rows], strip
-            )
+            metas = [meta[i] for i in rows]
+            out, halos = self._run_os_group(token, metas, strip)
+            if self.deep_reuse:
+                self._store_halos(halo_cache, metas, halos)
+            pending.append((rows, out))
+        outs: List[Optional[np.ndarray]] = [None] * len(meta)
+        with obs.span("executor.fetch"):
+            fetched = jax.device_get([out for _, out in pending])
+        for (rows, _), ys in zip(pending, fetched):
             for j, idx in enumerate(rows):
                 outs[idx] = ys[j]
-            if self.deep_reuse:
-                self._store_halos(halo_cache, [meta[i] for i in rows], halos)
         return np.stack(outs)
 
     def _partition(self, token, meta):
@@ -1069,7 +1076,8 @@ class PlanExecutor:
         Resolution inserts ``_PendingMiss`` markers, so repeated keys
         within the group dedup; groups run sequentially (full before
         strip), so the strip group sees the full group's fresh
-        ``_SpectrumRef``s.  Returns ``(outputs, halos)``.
+        ``_SpectrumRef``s.  Returns ``(out, halos)``, both still on the
+        device: the caller fetches ``out`` once every group is dispatched.
         """
         with obs.span("executor.resolve"):
             spec0 = self.compiled.layers[0].os_spec
@@ -1165,8 +1173,7 @@ class PlanExecutor:
             + sum(h.nbytes for h in halos)
         )
         self._store_spectra(token, cache, misses, F_m)
-        with obs.span("executor.fetch"):
-            return np.asarray(out), halos
+        return out, halos
 
     def _store_spectra(self, token, cache, misses, F_m) -> None:
         """File a group's miss spectra, split by absolute segment x.

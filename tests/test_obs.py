@@ -171,6 +171,32 @@ def test_served_spans_are_dotted_and_never_the_harness_names(params):
                if r.name in ("engine.schedule", "engine.write", "executor.dispatch"))
 
 
+def test_a_tick_files_everything_before_it_blocks_on_the_device(params):
+    """In a single-sweep tick the host resolves, dispatches and files every
+    group's spectra and halos before the tick's first ``executor.fetch``:
+    the store runs while the device computes, not after it has finished."""
+    eng, recs = _serve(params, [(1, SHAPES["interior"])])
+    stats = eng.executor.last_stats
+    assert stats["deep_strip_patches"] > 0 and stats["deep_full_patches"] > 0
+    by_sid = {r.sid: r for r in recs}
+
+    def tick_of(r):
+        while r.parent is not None:
+            r = by_sid[r.parent]
+        return r.sid
+
+    ticks: dict = {}
+    for r in recs:
+        if r.name.startswith("executor."):
+            ticks.setdefault(tick_of(r), []).append(r)
+    assert all(any(r.name == "executor.store" for r in rs) for rs in ticks.values())
+    for rs in ticks.values():
+        fetch = min(r.t0 for r in rs if r.name == "executor.fetch")
+        late = [r.name for r in rs if r.t0 > fetch
+                and r.name in ("executor.resolve", "executor.dispatch", "executor.store")]
+        assert not late, late
+
+
 def test_queued_marks_each_request_once(params):
     shapes = [SHAPES["interior"], SHAPES["cube"], SHAPES["interior"]]
     eng, recs = _serve(params, list(zip((11, 12, 13), shapes)))

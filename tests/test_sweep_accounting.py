@@ -22,7 +22,7 @@ from repro.configs.base import ConvLayerSpec as L, ConvNetConfig
 from repro.core import convnet, cost_model, planner
 from repro.core.hw import TPU_V5E
 from repro.volume import PlanExecutor
-from repro.volume.tiler import predict_sweep_counts
+from repro.volume.tiler import extract_patch, pad_volume, predict_sweep_counts
 
 NET = ConvNetConfig(
     "sweep-toy", 1,
@@ -123,6 +123,64 @@ def test_deep_reuse_reduces_interior_work(params, rng):
         == sd["deep_strip_patches"] * q
         + sd["deep_full_patches"] * spec0.n_segments
     )
+
+
+def _fetch_each_group(ex):
+    """Make ``ex`` wait for each group's output before its halos are
+    filed: one group at a time, the order the one fetch per batch replaced."""
+    run_group = ex._run_os_group
+
+    def fetched(*args, **kwargs):
+        out, halos = run_group(*args, **kwargs)
+        return np.asarray(out), halos
+
+    ex._run_os_group = fetched
+    return ex
+
+
+def test_one_fetch_after_full_and_strip_groups_changes_nothing(params, rng):
+    """Chunks of 3 over the interior shape, across x-planes (the engine's
+    plane cap never builds one; ``run_patch_batch`` takes any chunk): the
+    second holds a full group and a strip group, so its one fetch waits on
+    two dispatches.  Outputs, filed halo keys and the ledger peak equal
+    those of fetching each group before filing its halos; outputs also
+    match the self-contained walk of the extracted patches."""
+    shape = SHAPES["interior"]
+    vol = rng.normal(size=(1,) + shape).astype(np.float32)
+    runs = []
+    for ex in (
+        PlanExecutor(params, NET, prims=MIX, m=1, batch=3),
+        _fetch_each_group(PlanExecutor(params, NET, prims=MIX, m=1, batch=3)),
+    ):
+        tiling = ex.tiling_for(shape)
+        padded = pad_volume(vol, tiling)
+        token = ex.begin_sweep(padded)
+        specs = list(tiling.patches)
+        ticks = []
+        for i in range(0, len(specs), 3):
+            chunk = specs[i : i + 3]
+            strips, fulls = ex._deep_strips, ex._deep_fulls
+            ys = ex.run_patch_batch(
+                None, meta=[(token, tiling.segment_keys(s), s.start) for s in chunk]
+            )
+            ticks.append((
+                ex._deep_strips - strips, ex._deep_fulls - fulls, ys,
+                sorted(ex._halo_caches[token]), ex._ledger.peak,
+            ))
+        ex.end_sweep(token)
+        runs.append(ticks)
+        # after the sweep, so the walk's own transient leaves the peaks alone
+        for i, tick in zip(range(0, len(specs), 3), ticks):
+            xs = np.stack([extract_patch(padded, s, tiling.extent) for s in specs[i : i + 3]])
+            np.testing.assert_allclose(tick[2], ex.run_patch_batch(xs), rtol=0, atol=1e-5)
+    once, each = runs
+    assert [t[:2] for t in once] == [(0, 3), (2, 1), (2, 0)]
+    # halos filed at the x-successor of every core-aligned patch, as before
+    assert once[1][3] == [(x, y, 0) for x in (CORE, 2 * CORE, 3 * CORE) for y in (0, CORE)]
+    for a, b in zip(once, each):
+        np.testing.assert_array_equal(a[2], b[2])
+        assert a[3] == b[3]
+        assert a[4] == b[4] > 0
 
 
 def test_single_patch_volume_degenerates_to_full_path(params, rng):
