@@ -93,7 +93,8 @@ memory-model contract in docs/architecture.md).
 ``last_stats`` (patch/batch counts, wall seconds, measured vox/s with border
 waste, the planner's predicted vox/s, measured/predicted peak device bytes);
 its seconds and vox/s time that offline sweep alone.  On the served path,
-``counter_stats`` gives the reuse counters a ``VolumeEngine`` tick publishes,
+``counter_stats`` gives the reuse counters and the conv FLOPs dispatched
+(``conv_flops``) that a ``VolumeEngine`` tick publishes,
 and ``repro.obs`` spans time the host work (``executor.begin_sweep``,
 ``executor.resolve``, ``executor.dispatch``, ``executor.store``,
 ``executor.fetch``): a single-sweep batch dispatches every group and stores
@@ -168,6 +169,22 @@ def _permute_conv_params(params, net: ConvNetConfig, perm: Tuple[int, int, int])
         else:
             out.append(p)
     return out
+
+
+def _flat_halo(h: jnp.ndarray) -> jnp.ndarray:
+    """(B, f, w, ny, nz) -> (B, f, w·ny·nz): the form a halo is cached in.
+
+    A halo waits on the device for its x-successor, beside the full steps
+    that run meanwhile.  Laid out 5-D, its minor dims pad to whole (8, 128)
+    tiles (f=80 or n=44 to 128 lanes on a TPU: up to 1.6x the data); one
+    flat minor axis pads at most one lane tile.
+    """
+    return h.reshape(h.shape[:2] + (-1,))
+
+
+def _unflat_halo(h: jnp.ndarray, cross: Tuple[int, int]) -> jnp.ndarray:
+    """Inverse of ``_flat_halo`` for a halo of cross extents ``cross``."""
+    return h.reshape(h.shape[:2] + (-1,) + tuple(cross))
 
 
 class _PendingMiss(NamedTuple):
@@ -383,6 +400,11 @@ class PlanExecutor:
         self._os_mad_segments = 0
         self._deep_strips = 0
         self._deep_fulls = 0
+        # convolution FLOPs of every dispatched step, from the layers' static
+        # shapes: what a patch's full walk and its strip walk compute
+        self._conv_flops = 0
+        self._full_conv_flops = self._walk_conv_flops(strip=False)
+        self._strip_conv_flops = self._walk_conv_flops(strip=True)
         self._fused_pair_calls = 0
         self._os_fused_segments = 0
         # layers below the input the halo-emitting fused epilogue can serve
@@ -476,6 +498,28 @@ class PlanExecutor:
         identity.
         """
         return plan_input_size(self.net, self.prims, self.m)
+
+    def _walk_conv_flops(self, strip: bool) -> int:
+        """Σ 2·k³·f·f' × conv output voxels of one patch's walk.
+
+        The full walk convolves every MPF fragment at every layer's whole
+        extent (P³ shifted passes where the pools are plain); the strip
+        walk's convs output only the patch's new core columns in x.
+        """
+        n, p_cur, frag, f, total = self.n_in, 1, 1, self.net.in_channels, 0
+        for i, layer in enumerate(self.net.layers):
+            if layer.kind == "conv":
+                k, fo = layer.size, layer.out_channels
+                n = n - k + 1
+                cols = self.core // p_cur if strip else n
+                total += 2 * k**3 * f * fo * frag * cols * n * n
+                f = fo
+            else:
+                n //= layer.size
+                p_cur *= layer.size
+                if self.prims[i] == "mpf":
+                    frag *= layer.size**3
+        return total if self.uses_mpf else total * self.P**3
 
     def tiling_for(
         self, vol_shape: Sequence[int], *, sweep_axis: Optional[int] = None
@@ -778,7 +822,8 @@ class PlanExecutor:
         Returns ``(seg_row_bytes, halo_entry_bytes)``: one layer-0 segment
         spectrum row is the complex64 rfftn of an (f_in, *fft_shape) block;
         one activation-halo entry stacks, per layer below the input, the
-        (frag, C_in, size-1, n, n) float32 capture of the strip walk.
+        (frag, C_in, size-1, n, n) float32 capture of the strip walk, kept
+        lane-flat as (frag, C_in, (size-1)·n·n).
         Every key's entry has the same size (patch extent is constant), so
         ``predict_shard_handoff`` counts x per-entry sizes give the exact
         exchanged bytes.
@@ -833,7 +878,7 @@ class PlanExecutor:
             with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
                 if capture:
                     h = self.net.layers[i].size - 1
-                    halos.append(x[:, :, -h:])
+                    halos.append(_flat_halo(x[:, :, -h:]))
                 if fused:
                     nxt = self.compiled.layers[i + 1]
                     x, pool_halo = fft_conv_pool_fused_halo(
@@ -844,7 +889,7 @@ class PlanExecutor:
                         fprime_chunk=pl.fprime_chunk,
                     )
                     if capture:
-                        halos.append(pool_halo)
+                        halos.append(_flat_halo(pool_halo))
                     i += 2
                     continue
                 x = resolve_primitive(pl).apply(
@@ -949,8 +994,9 @@ class PlanExecutor:
                 h, _ = self._strip_info[i]
                 fused = self.fuse_os and i in self._fused_pairs
                 with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
-                    x = jnp.concatenate([halos[i - 1], x], axis=2)
-                    new_halos.append(x[:, :, -h:])
+                    lead = _unflat_halo(halos[i - 1], x.shape[3:])
+                    x = jnp.concatenate([lead, x], axis=2)
+                    new_halos.append(_flat_halo(x[:, :, -h:]))
                     if fused:
                         # fused pair: the pool layer's input is the cached
                         # lead halo ``halos[i]`` + the conv's ReLU output —
@@ -962,11 +1008,15 @@ class PlanExecutor:
                         x, pool_halo = fft_conv_pool_fused_halo(
                             x, strip_states[i]["W"], strip_states[i]["b"],
                             fft_shape=pl.fft_shape, k=pl.kernel_size,
-                            p=nxt.pool_size, halo_cols=h_pool, lead=halos[i],
+                            p=nxt.pool_size, halo_cols=h_pool,
+                            lead=_unflat_halo(
+                                halos[i],
+                                tuple(n - self.net.layers[i].size + 1 for n in x.shape[3:]),
+                            ),
                             use_pallas=self.use_pallas,
                             fprime_chunk=pl.fprime_chunk,
                         )
-                        new_halos.append(pool_halo)
+                        new_halos.append(_flat_halo(pool_halo))
                         i += 2
                         continue
                     x = resolve_primitive(pl).apply(
@@ -1154,6 +1204,7 @@ class PlanExecutor:
                     starts, tuple(parents), halos_in, pattern=tuple(pattern),
                 )
             self._deep_strips += len(metas)
+            self._conv_flops += len(metas) * self._strip_conv_flops
         else:
             self._record_trace(
                 ("full", tuple(pattern), None if starts is None else len(misses),
@@ -1165,6 +1216,7 @@ class PlanExecutor:
                     starts, tuple(parents), pattern=tuple(pattern),
                 )
             self._deep_fulls += len(metas)
+            self._conv_flops += len(metas) * self._full_conv_flops
         # the ledger's transient sample: group output + miss spectra +
         # captured halos in flight on top of the resident working set
         self._ledger.transient(
@@ -1316,6 +1368,7 @@ class PlanExecutor:
             states, _ = self._states_for_axis(axis)
             with obs.span("executor.dispatch"):
                 out, _ = self._jit_os_walk(states, F_all)
+            self._conv_flops += len(rows) * self._full_conv_flops
             self._ledger.transient(F_all.nbytes + out.nbytes)
             with obs.span("executor.fetch"):
                 out = np.asarray(out)
@@ -1366,6 +1419,7 @@ class PlanExecutor:
         S = xs.shape[0]
         self._seen_batch_sizes.add(S)
         states = self.compiled.states
+        self._conv_flops += S * self._full_conv_flops
         if self.uses_mpf:
             self._record_trace(("walk", xs.shape))
             with obs.span("executor.dispatch"):
@@ -1414,7 +1468,7 @@ class PlanExecutor:
         )
 
         self._os_misses = self._os_hits = self._os_mad_segments = 0
-        self._deep_strips = self._deep_fulls = 0
+        self._deep_strips = self._deep_fulls = self._conv_flops = 0
         self._fused_pair_calls = self._os_fused_segments = 0
         self._ledger.begin_run()  # peak scoped to this sweep
         t0 = time.perf_counter()
@@ -1496,6 +1550,9 @@ class PlanExecutor:
             "os_mad_segments": self._os_mad_segments,
             "deep_strip_patches": self._deep_strips,
             "deep_full_patches": self._deep_fulls,
+            # convolution FLOPs of the steps dispatched, full and strip:
+            # Σ 2·k³·f·f' × conv output voxels each computed
+            "conv_flops": self._conv_flops,
         }
 
     # -- memory model --------------------------------------------------------
@@ -1663,6 +1720,7 @@ class PlanExecutor:
         ys = np.asarray(
             self._pipeline_fn(self.compiled.states, jnp.asarray(xs_all))
         )
+        self._conv_flops += T * S * self._full_conv_flops
         # ring hand-off: pod p's local outputs are pod p-1's patches; roll
         # the pod-major chunk axis by one local-stream length to realign.
         if n_pods > 1:
@@ -1749,6 +1807,7 @@ class PlanExecutor:
             xfer_s += t3 - t2
             xfer_bytes += float(a_host.nbytes)
             y = jit1(states1, a1)
+            self._conv_flops += len(chunk) * self._full_conv_flops
             y.block_until_ready()
             stage1_s += time.perf_counter() - t3
             self._ledger.transient(xs.nbytes + a.nbytes + y.nbytes)

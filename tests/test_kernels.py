@@ -6,7 +6,7 @@ import pytest
 
 from repro.kernels.cmul_mad import ops as cmul_ops, ref as cmul_ref
 from repro.kernels.decode_attn import ops as da_ops, ref as da_ref
-from repro.kernels.direct_conv3d import ops as c3_ops, ref as c3_ref
+from repro.kernels.direct_conv3d import kernel as c3_kernel, ops as c3_ops, ref as c3_ref
 from repro.kernels.mpf_pool import ops as mp_ops, ref as mp_ref
 
 
@@ -47,6 +47,32 @@ def test_direct_conv3d_sweep(S, f, fp, n, k, rng):
     got = c3_ops.conv3d(x, w, use_pallas=True)
     want = c3_ref.conv3d(x, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3, rtol=1e-4)
+
+
+@pytest.mark.parametrize("fp", [8, 3])
+def test_direct_conv3d_k9(fp, rng):
+    """n926's kernel size at a toy width.  Through the wrapper: one lane
+    block, nine dx steps accumulating into the output block.  Then the
+    kernel with lanes cut into 128-lane blocks (240 output lanes: two, the
+    second ragged), each reading its (k-1)·(nz+1)-lane halo past the
+    block, and x tiles of 5 planes over 12 (the last ragged)."""
+    S, f, k = 2, 8, 9
+    x = rng.normal(size=(S, f, 14, 14, 14)).astype(np.float32)
+    w = rng.normal(size=(fp, f, k, k, k)).astype(np.float32)
+    got = c3_ops.conv3d(jnp.asarray(x), jnp.asarray(w), use_pallas=True)
+    want = c3_ref.conv3d(jnp.asarray(x), jnp.asarray(w))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-3, rtol=1e-4)
+
+    n = 20
+    m = n - k + 1
+    x = rng.normal(size=(S, f, n, n, n)).astype(np.float32)
+    assert c3_kernel.lane_geometry(k, n, n * n, 128)[0] == 2
+    planes = jnp.asarray(x.transpose(0, 2, 1, 3, 4).reshape(S, n, f, n * n))
+    taps = jnp.asarray(w.transpose(2, 3, 4, 0, 1).reshape(k**3, fp, f))
+    o = c3_kernel.conv3d_planes(planes, taps, k=k, nz=n, tx=5, kd=1, lb=128, fpb=fp)
+    got = np.asarray(o).reshape(S, m, fp, m, n)[..., :m].transpose(0, 2, 1, 3, 4)
+    want = c3_ref.conv3d(jnp.asarray(x), jnp.asarray(w))
+    np.testing.assert_allclose(got, np.asarray(want), atol=1e-3, rtol=1e-4)
 
 
 # --------------------------------------------------------------------------

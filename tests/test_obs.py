@@ -253,3 +253,28 @@ def test_programs_name_their_layers(params):
                       "recombine"):
             assert any(s.startswith(walk + layer) for s in scopes), (top, layer)
         assert any(s.startswith(f"{top}/L0.spectra") for s in scopes)
+
+
+def test_conv_flops_counts_each_dispatched_walk(params):
+    """``conv_flops``: Σ 2·k³·f·f' × conv output voxels of every step the
+    executor dispatched, an exact int, cumulative like the other
+    ``counter_stats`` entries.  Two patches in x, one per tick: the first
+    walks its whole 21³ extent (layer 0 to 19³, 8 MPF fragments of 7³ at
+    layer 2, 64 of 1³ at layer 4), the second the strip path, whose convs
+    output only its new core columns (4, then 2 and 1 per fragment)."""
+    full = (2 * 27 * 1 * 4 * 19**3          # layer 0: 1 -> 4 maps
+            + 2 * 27 * 4 * 4 * 8 * 7**3     # layer 2: 8 fragments
+            + 2 * 27 * 4 * 2 * 64 * 1**3)   # layer 4: 64 fragments, 4 -> 2 maps
+    strip = (2 * 27 * 1 * 4 * 4 * 19**2
+             + 2 * 27 * 4 * 4 * 8 * 2 * 7**2
+             + 2 * 27 * 4 * 2 * 64 * 1)
+    assert (full, strip) == (3_880_008, 1_016_928)
+    eng = VolumeEngine(params, NET, prims=MIX, m=1, batch=1, tuned=None)
+    eng.submit(VolumeRequest(0, _volume((2 * CORE + FOV - 1,) + (CORE + FOV - 1,) * 2)))
+    seen = []
+    while eng.step():
+        stats = eng.executor.last_stats
+        seen.append((stats["deep_full_patches"], stats["deep_strip_patches"],
+                     stats["conv_flops"]))
+    assert seen == [(1, 0, full), (1, 1, full + strip)]
+    assert type(eng.executor.counter_stats()["conv_flops"]) is int

@@ -269,3 +269,27 @@ def test_plan_single_volume_shape_search(params):
         NET, TPU_V5E, chips=4, volume_shape=shape
     )
     assert strategies["single"] is not None
+
+
+def test_cached_halos_are_lane_flat_and_sized_as_predicted(params, rng):
+    """Every filed halo is kept as (frag·B, f, w·n·n): one flat minor axis,
+    so a halo waiting for its x-successor pads at most one lane tile.  An
+    entry holds exactly the bytes ``handoff_entry_nbytes`` predicts, and
+    the strip path that reads them back still equals the dense oracle."""
+    vol = rng.normal(size=(1,) + SHAPES["interior"]).astype(np.float32)
+    ex = PlanExecutor(params, NET, prims=MIX, m=1, batch=1)
+    filed = []
+    store = ex._store_halos
+
+    def recording(halo_cache, metas, halos):
+        store(halo_cache, metas, halos)
+        filed.extend(halo_cache.values())
+
+    ex._store_halos = recording
+    got = ex.run(vol)
+    np.testing.assert_allclose(got, _dense(params, vol), atol=1e-3)
+    assert ex.last_stats["deep_strip_patches"] > 0 and filed
+    _, entry_bytes = ex.handoff_entry_nbytes()
+    for entry in filed:
+        assert [h.ndim for h in entry] == [3] * (len(NET.layers) - 1)
+        assert sum(h.nbytes for h in entry) == entry_bytes

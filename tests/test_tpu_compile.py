@@ -1,4 +1,5 @@
-"""Every volume-path Pallas kernel compiles for a TPU v5e at n337 widths.
+"""Every volume-path Pallas kernel compiles for a TPU v5e at n337 widths,
+and ``direct_conv3d`` at n926's k=9 layers too.
 
 Interpret mode (the other kernel tests) cannot see what the chip's
 compiler refuses: unsupported layouts, lane-misaligned dynamic slices,
@@ -124,6 +125,21 @@ def test_direct_conv3d_compiles(one_chip, S, n, fp):
         lambda x, w: conv_ops.conv3d(x, w, use_pallas=True, interpret=False),
         _sds(one_chip, (S, f, n, n, n)),
         _sds(one_chip, (fp, f, k, k, k)),
+    )
+
+
+@pytest.mark.parametrize(
+    "S,n,fp", [(8, 97, F), (64, 44, F), (64, 20, 3)], ids=["conv2", "conv4", "conv7"],
+)
+def test_direct_conv3d_n926_compiles(one_chip, S, n, fp):
+    """n926's k=9 convs at 80 maps (m=12, batch 1: 202³ patches).  The
+    weight block holds one dx of the taps, the staged planes one lane block
+    and its halo, the row body 81 dots over at most eight 128-lane chunks,
+    so each compiles in seconds and within the scoped VMEM limit."""
+    _compile(
+        lambda x, w: conv_ops.conv3d(x, w, use_pallas=True, interpret=False),
+        _sds(one_chip, (S, F, n, n, n)),
+        _sds(one_chip, (fp, F, 9, 9, 9)),
     )
 
 
