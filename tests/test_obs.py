@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import ConvLayerSpec as L, ConvNetConfig
@@ -278,3 +279,26 @@ def test_conv_flops_counts_each_dispatched_walk(params):
                      stats["conv_flops"]))
     assert seen == [(1, 0, full), (1, 1, full + strip)]
     assert type(eng.executor.counter_stats()["conv_flops"]) is int
+
+
+@pytest.mark.parametrize("f,fp,n,kz", [(8, 8, 13, 3), (3, 8, 13, 1), (8, 8, 7, 1), (8, 2, 13, 1)])
+def test_direct_conv3d_tiling_mark_records_the_stacking(f, fp, n, kz):
+    """``direct_conv3d.tiling`` records the picker's stacking: the dz taps
+    a dot holds (``taps_per_dot``: all three at k=3 where f fills whole
+    staged tiles, f' is 8 or more and a row spans more than 128 lanes,
+    else one) and the contraction they make (``contract``: taps × f)."""
+    from repro.kernels.direct_conv3d import ops as conv_ops
+
+    k = 3
+    x = jnp.ones((1, f, n, n, n), jnp.float32)
+    w = jnp.ones((fp, f, k, k, k), jnp.float32)
+    t0 = time.perf_counter()
+    jax.eval_shape(lambda x, w: conv_ops.conv3d(x, w, use_pallas=True, interpret=True), x, w)
+    marks = [r for r in obs.spans(t0)[0] if r.name == "direct_conv3d.tiling"]
+    assert len(marks) == 1
+    b = conv_ops._pick_blocking(n - k + 1, k, f, fp, n, n * n, (n - k + 1) * n,
+                                conv_ops.stage_dtype(f, interpret=True))
+    attrs = marks[0].attrs
+    assert (attrs["taps_per_dot"], attrs["contract"]) == (b.kz, b.kz * f) == (kz, kz * f)
+    assert (attrs["tx"], attrs["dx_steps"], attrs["vmem_bytes"]) == (b.tx, k // b.kd, b.vmem)
+    assert attrs["stage"] == "float32"

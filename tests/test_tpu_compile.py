@@ -132,9 +132,11 @@ def test_direct_conv3d_compiles(one_chip, S, n, fp):
     "S,n,fp", [(8, 97, F), (64, 44, F), (64, 20, 3)], ids=["conv2", "conv4", "conv7"],
 )
 def test_direct_conv3d_n926_compiles(one_chip, S, n, fp):
-    """n926's k=9 convs at 80 maps (m=12, batch 1: 202³ patches).  The
-    weight block holds one dx of the taps, the staged planes one lane block
-    and its halo, the row body 81 dots over at most eight 128-lane chunks,
+    """n926's k=9 convs at 80 maps (m=12, batch 1: 202³ patches), conv 7
+    at f'=3.  The weight block holds one dx of the taps; each plane of a
+    lane block and its halo is staged once in bfloat16 with three dz
+    shifts stacked (a contraction of 240; one tap a dot at f'=3); the row
+    body is 27 dots of 2 MXU passes over at most fourteen 128-lane chunks,
     so each compiles in seconds and within the scoped VMEM limit."""
     _compile(
         lambda x, w: conv_ops.conv3d(x, w, use_pallas=True, interpret=False),
