@@ -20,7 +20,6 @@ from . import (
     table2_memory,
     table4_primitives,
     table5_throughput,
-    volume_throughput,
 )
 from .common import emit
 
@@ -64,7 +63,6 @@ def main() -> None:
         fig7_memory,
     ):
         mod.main()
-    volume_throughput.main([])  # explicit argv: don't re-parse run.py's
     dryrun_summary()
 
 

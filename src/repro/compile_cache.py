@@ -1,7 +1,7 @@
 """Where JAX keeps its persistent compilation cache.
 
-Every entry point (``chip_smoke.py``, ``benchmarks/volume_throughput.py``,
-``examples/serve_volume.py``, ``python -m repro.tuning.autotune``) calls
+Every entry point (``chip_smoke.py``, ``examples/serve_volume.py``,
+``python -m repro.tuning.autotune``) calls
 ``enable_compile_cache()`` before its first compile, so that processes of
 one run, and runs on one machine, share compiled programs.
 

@@ -61,8 +61,8 @@ ZNNI_NETS = {c.name: c for c in (N337, N537, N726, N926)}
 
 # The CI-sized benchmark net (not a paper net): 8 input channels so layer-0
 # input transforms carry real work, small enough that the full strategy
-# matrix sweeps in seconds.  Shared by benchmarks/volume_throughput.py and
-# repro.tuning.autotune so the tuned-config key "bench-net" means one net.
+# matrix sweeps in seconds.  The net repro.tuning.autotune tunes by
+# default, so the tuned-config key "bench-net" means one net.
 BENCH_NET = ConvNetConfig(
     name="bench-net",
     in_channels=8,

@@ -433,6 +433,39 @@ def prepare_layers(
     return tuple(prepared)
 
 
+def last_conv_index(net: ConvNetConfig) -> int:
+    """Index of the net's last conv layer: the one conv no ReLU follows."""
+    return max(i for i, l in enumerate(net.layers) if l.kind == "conv")
+
+
+def activate(net: ConvNetConfig, i: int, x):
+    """The activation rule every walk shares: ReLU after every conv but
+    the net's last (``x`` is layer ``i``'s output)."""
+    if net.layers[i].kind == "conv" and i != last_conv_index(net):
+        return jax.nn.relu(x)
+    return x
+
+
+def fused_pair_starts(
+    net: ConvNetConfig, prepared: Sequence[PreparedLayer]
+) -> Tuple[int, ...]:
+    """The fused-pair rule: layer indices of the ``fft_cached`` convs among
+    ``prepared`` that are not the net's last conv and are directly
+    followed by their ``mpf`` pool.  A fused call applies the ReLU between
+    the two, which is why the net's last conv never qualifies."""
+    last_conv = last_conv_index(net)
+    return tuple(
+        pl.index
+        for pl, nxt in zip(prepared, prepared[1:])
+        if pl.kind == "conv"
+        and pl.prim == "fft_cached"
+        and pl.index != last_conv
+        and nxt.kind == "pool"
+        and nxt.prim == "mpf"
+        and nxt.index == pl.index + 1
+    )
+
+
 def apply_prepared_range(
     net: ConvNetConfig,
     prepared: Sequence[PreparedLayer],
@@ -444,54 +477,41 @@ def apply_prepared_range(
 ):
     """Walk prepared layers over ``x``: the thin core of plan execution.
 
-    ReLU follows the whole-net rule (no activation after the net's final
-    conv), so chaining ranges composes to a full forward pass.  ``states``
-    (when given) substitutes each layer's pytree state — the hook jitted
-    callers use to pass cached spectra as arguments rather than constants.
+    ReLU follows the whole-net rule (``activate``), so chaining ranges
+    composes to a full forward pass.  ``states`` (when given) substitutes
+    each layer's pytree state — the hook jitted callers use to pass cached
+    spectra as arguments rather than constants.
 
-    With ``fuse_pairs`` a consecutive ``fft_cached`` conv + ``mpf`` pool
-    pair dispatches to ``fft_conv_pool_fused`` (bias on the MAD's DC bin,
-    inverse-window crop folded into the pool, ReLU after the pool) instead
-    of two separate primitive applies — numerically equivalent, fewer
-    materialized intermediates.
+    With ``fuse_pairs`` each pair ``fused_pair_starts`` picks dispatches to
+    ``fft_conv_pool_fused`` (bias on the MAD's DC bin, inverse-window crop
+    folded into the pool, ReLU after the pool) instead of two separate
+    primitive applies — numerically equivalent, fewer materialized
+    intermediates.
 
     Each layer traces under ``jax.named_scope("L<index>.<prim>")`` (a
     fused pair under ``L<index>.<prim>_mpf``), so the device ops of a
     profiler trace name the layer that issued them.
     """
-    last_conv = max(i for i, l in enumerate(net.layers) if l.kind == "conv")
-    if states is None:
-        states = [pl.state for pl in prepared]
-    else:
-        states = list(states)
     prepared = tuple(prepared)
+    states = [pl.state for pl in prepared] if states is None else list(states)
+    pairs = fused_pair_starts(net, prepared) if fuse_pairs else ()
     i = 0
     while i < len(prepared):
         pl = prepared[i]
         st = states[i]
-        nxt = prepared[i + 1] if i + 1 < len(prepared) else None
-        if (
-            fuse_pairs
-            and pl.kind == "conv"
-            and pl.prim == "fft_cached"
-            and pl.index != last_conv  # fused path applies the ReLU
-            and nxt is not None
-            and nxt.kind == "pool"
-            and nxt.prim == "mpf"
-            and nxt.index == pl.index + 1
-        ):
+        if pl.index in pairs:
             with jax.named_scope(f"L{pl.index}.{pl.prim}_mpf"):
                 x = fft_conv_pool_fused(
                     x, st["W"], st["b"],
-                    fft_shape=pl.fft_shape, k=pl.kernel_size, p=nxt.pool_size,
+                    fft_shape=pl.fft_shape, k=pl.kernel_size,
+                    p=prepared[i + 1].pool_size,
                     use_pallas=use_pallas, fprime_chunk=pl.fprime_chunk,
                 )
             i += 2
             continue
         with jax.named_scope(f"L{pl.index}.{pl.prim}"):
             x = _resolve(pl).apply(pl, x, st, use_pallas=use_pallas)
-            if pl.kind == "conv" and pl.index != last_conv:
-                x = jax.nn.relu(x)
+            x = activate(net, pl.index, x)
         i += 1
     return x
 

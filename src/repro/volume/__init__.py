@@ -47,7 +47,7 @@ over a volume far larger than any single patch:
   every request shares the executor's one CompiledPlan.
 
 Entry points: ``examples/serve_volume.py`` (service demo) and
-``benchmarks/volume_throughput.py`` (measured vs. predicted vox/s).
+``bench/run.py`` (the benchmark's served cells, ``BENCHMARK.json``).
 
 Test-suite conventions (repo-wide, recorded here per ISSUE 1):
 * slow tests carry ``@pytest.mark.slow`` and are deselected by default via

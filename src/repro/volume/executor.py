@@ -62,8 +62,12 @@ race on intra-chunk dependencies.  ``last_stats`` adds
 jit specializations seen).  ``predict_counts`` returns the planner-side
 ``SweepCounts`` for a volume shape — by construction these equal the
 measured counters exactly (the sweep-aware planning acceptance property).
-``fuse_os`` additionally routes eligible ``fft_cached``+``mpf`` pairs of
-the capture and strip walks through the halo-emitting fused epilogue
+Every step walks layers 1.. through one walk (``_walk_below_input``: the
+full, capture and strip walks differ only in their layers, cached leads
+and ``capture``), and every batch maps segment keys to cache entries
+through one resolver (``_resolve_segments``).  ``fuse_os`` additionally
+routes eligible ``fft_cached``+``mpf`` pairs of the capture and strip
+walks through the halo-emitting fused epilogue
 (``fft_conv_pool_fused_halo``): the pool input is never materialized as a
 walk step but its trailing columns still reach the halo cache via the
 fused call's second output — dense output and exported halos are bitwise
@@ -125,8 +129,10 @@ from ..core.planner import Plan
 from ..core.primitives import (
     CompiledPlan,
     PreparedLayer,
+    activate,
     compile_plan,
     conv_primitive,
+    fused_pair_starts,
     plan_input_size,
     pool_primitive,
     resolve_primitive,
@@ -185,6 +191,14 @@ def _flat_halo(h: jnp.ndarray) -> jnp.ndarray:
 def _unflat_halo(h: jnp.ndarray, cross: Tuple[int, int]) -> jnp.ndarray:
     """Inverse of ``_flat_halo`` for a halo of cross extents ``cross``."""
     return h.reshape(h.shape[:2] + (-1,) + tuple(cross))
+
+
+def _layer_states(strip) -> Optional[List[Any]]:
+    """The strip walk's state pytree from ``_build_strip_plan``'s
+    ``(layers, info)`` (None without deep reuse)."""
+    if strip is None:
+        return None
+    return [pl.state if pl is not None else None for pl in strip[0]]
 
 
 class _PendingMiss(NamedTuple):
@@ -354,20 +368,23 @@ class PlanExecutor:
             self.extent, self.core, self.fov
         )
         self.out_channels = [l for l in net.layers if l.kind == "conv"][-1].out_channels
+        # overlap-save input-spectra reuse: active when the patch walk starts
+        # with an overlap_save conv over the full patch extent (MPF plans; the
+        # plain-pool subsampling sweep slices shifted sub-windows, which
+        # breaks segment alignment).  Deep activation reuse: interior patches
+        # run a strip walk assembled from cached per-layer activation halos
+        # (see module docstring).
+        self._os_reuse = self.prims[0] == "overlap_save" and self.uses_mpf
+        self.deep_reuse = bool(self._os_reuse and deep_reuse)
 
         # one-time setup for every layer (cached kernel spectra, per-layer
         # FFT shapes, pool modes) — shared by every compiled batch size and
-        # by the pipeline2 stage functions.  A first-layer overlap_save conv
-        # gets its segment grid pinned to the patch core so x-adjacent
-        # patches share segment spectra (cross-patch input-FFT reuse).
-        self.compiled: CompiledPlan = compile_plan(
-            self.params, net, prims=self.prims, n_in=self.n_in,
-            use_pallas=self.use_pallas, fuse_pairs=fuse_pairs,
-            fprime_chunk=fprime_chunk, plan=plan,
-            overlap_seg=self.core if self.prims[0] == "overlap_save" else None,
-        )
-        self.fuse_pairs = self.compiled.fuse_pairs
+        # by the pipeline2 stage functions; ``fuse_pairs`` is None until
+        # compile_plan resolves it.
+        self.fuse_pairs = fuse_pairs
         self._fprime_chunk = fprime_chunk
+        self.compiled, strip = self._prepare_frame(self.params)
+        self.fuse_pairs = self.compiled.fuse_pairs
 
         recombine = self.uses_mpf
 
@@ -385,10 +402,6 @@ class PlanExecutor:
         self.last_stats: Dict[str, float] = {}
 
         # -- overlap-save input-spectra reuse state --------------------------
-        # active when the patch walk starts with an overlap_save conv over
-        # the full patch extent (MPF plans; the plain-pool subsampling sweep
-        # slices shifted sub-windows, which breaks segment alignment).
-        self._os_reuse = self.prims[0] == "overlap_save" and self.uses_mpf
         self._sweeps: Dict[int, Dict[Tuple[int, int, int], jnp.ndarray]] = {}
         self._sweep_vols: Dict[int, jnp.ndarray] = {}  # non-streaming scopes
         self._sweep_hosts: Dict[int, np.ndarray] = {}  # streaming scopes
@@ -405,38 +418,15 @@ class PlanExecutor:
         self._conv_flops = 0
         self._full_conv_flops = self._walk_conv_flops(strip=False)
         self._strip_conv_flops = self._walk_conv_flops(strip=True)
-        self._fused_pair_calls = 0
-        self._os_fused_segments = 0
-        # layers below the input the halo-emitting fused epilogue can serve
-        # as a conv+pool pair (same eligibility as apply_prepared_range's
-        # fuse_pairs: fft_cached conv, not the net's last conv, immediately
-        # followed by its mpf pool)
-        _last_conv = max(
-            i for i, l in enumerate(net.layers) if l.kind == "conv"
-        )
-        pairs = []
-        for i in range(1, len(net.layers) - 1):
-            pl_i = self.compiled.layers[i]
-            nxt = self.compiled.layers[i + 1]
-            if (
-                pl_i.kind == "conv"
-                and pl_i.prim == "fft_cached"
-                and pl_i.index != _last_conv
-                and nxt.kind == "pool"
-                and nxt.prim == "mpf"
-                and nxt.index == pl_i.index + 1
-            ):
-                pairs.append(i)
-        self._fused_pairs: Tuple[int, ...] = tuple(pairs)
+        # the conv+pool pairs below the input the halo-emitting fused
+        # epilogue serves in the walks below layer 0 (``fused_pair_starts``)
+        self._fused_pairs = fused_pair_starts(net, self.compiled.layers[1:])
         # fused halo-emitting epilogue in the capture/strip walks: off by
         # default (conservative; tuned configs switch it on per hardware),
         # and a no-op unless the plan has fusable pairs and runs the
         # overlap-save reuse walks at all
-        self.fuse_os = bool(fuse_os) and bool(pairs) and self._os_reuse
+        self.fuse_os = bool(fuse_os) and bool(self._fused_pairs) and self._os_reuse
         self._trace_keys: set = set()  # distinct jit specializations seen
-        # deep activation reuse: interior patches run a strip walk assembled
-        # from cached per-layer activation halos (see module docstring)
-        self.deep_reuse = bool(self._os_reuse and deep_reuse)
         self._halo_caches: Dict[int, Dict[Tuple[int, int, int], List]] = {}
         if self._os_reuse:
             spec0 = self.compiled.layers[0].os_spec
@@ -452,10 +442,7 @@ class PlanExecutor:
             spec0 = self.compiled.layers[0].os_spec
             # trailing segments covering an interior patch's new core columns
             self._q_strip = os_mod.tail_segments(spec0, self.core)
-            self._strip_layers, self._strip_info = self._build_strip_plan()
-            self._strip_states = [
-                pl.state if pl is not None else None for pl in self._strip_layers
-            ]
+            self._strip_layers, self._strip_info = strip
             self._jit_os_strip_step = jax.jit(
                 self._os_strip_step, static_argnames=("pattern",)
             )
@@ -469,16 +456,15 @@ class PlanExecutor:
         # every working frame shape-identical.
         self._sweep_axes: Dict[int, int] = {}
         self._axis_states: Dict[int, Tuple[Any, Any]] = {
-            self.sweep_axis: (
-                self.compiled.states, getattr(self, "_strip_states", None)
-            )
+            self.sweep_axis: (self.compiled.states, _layer_states(strip))
         }
         # device-working-set ledger: prepared states (weights, cached kernel
         # spectra at full AND strip shapes) are resident for the executor's
         # lifetime; sweeps add slabs/caches on top.
         self._ledger = _DeviceLedger()
-        strip_states = getattr(self, "_strip_states", [])
-        self._ledger.alloc(_tree_nbytes(self.params, self.compiled.states, strip_states))
+        self._ledger.alloc(
+            _tree_nbytes(self.params, *self._axis_states[self.sweep_axis])
+        )
         self._predict_memory_cache: Dict[Tuple[int, int, int], Any] = {}
 
     def tuned_provenance(self) -> Optional[Dict[str, Any]]:
@@ -585,29 +571,33 @@ class PlanExecutor:
         """
         got = self._axis_states.get(axis)
         if got is None:
-            p_ax = _permute_conv_params(
-                self._orig_params, self.net, sweep_perm(axis)
+            compiled, strip = self._prepare_frame(
+                _permute_conv_params(self._orig_params, self.net, sweep_perm(axis))
             )
-            compiled = compile_plan(
-                p_ax, self.net, prims=self.prims, n_in=self.n_in,
-                use_pallas=self.use_pallas, fuse_pairs=self.fuse_pairs,
-                fprime_chunk=self._fprime_chunk, plan=self.plan,
-                overlap_seg=(
-                    self.core if self.prims[0] == "overlap_save" else None
-                ),
-            )
-            strip_states = None
-            if self.deep_reuse:
-                layers, _ = self._build_strip_plan(p_ax)
-                strip_states = [
-                    pl.state if pl is not None else None for pl in layers
-                ]
-            got = (compiled.states, strip_states)
-            self._axis_states[axis] = got
-            self._ledger.alloc(_tree_nbytes(got[0], strip_states or []))
+            got = self._axis_states[axis] = (compiled.states, _layer_states(strip))
+            self._ledger.alloc(_tree_nbytes(*got))
         return got
 
-    def _build_strip_plan(self, params=None):
+    def _prepare_frame(self, params) -> Tuple[CompiledPlan, Optional[Tuple]]:
+        """``(compiled, strip)`` for one working frame's conv weights.
+
+        Every axis's prepared layers come from here: the primary axis's at
+        construction, the others' in ``_states_for_axis``.  A first-layer
+        overlap_save conv gets its segment grid pinned to the patch core so
+        x-adjacent patches share segment spectra (cross-patch input-FFT
+        reuse).  ``strip`` is ``_build_strip_plan``'s ``(layers, info)``
+        under deep reuse, else None.
+        """
+        compiled = compile_plan(
+            params, self.net, prims=self.prims, n_in=self.n_in,
+            use_pallas=self.use_pallas, fuse_pairs=self.fuse_pairs,
+            fprime_chunk=self._fprime_chunk, plan=self.plan,
+            overlap_seg=self.core if self.prims[0] == "overlap_save" else None,
+        )
+        strip = self._build_strip_plan(params) if self.deep_reuse else None
+        return compiled, strip
+
+    def _build_strip_plan(self, params):
         """One-time setup of the interior-patch strip walk (layers >= 1).
 
         For each layer below the input, bind its primitive to the strip
@@ -617,11 +607,8 @@ class PlanExecutor:
         where ``layers[i]`` is the strip ``PreparedLayer`` (None at 0 —
         layer 0 runs through the segment-spectra tail) and ``info[i] =
         (halo columns, fragment batch multiplier at this layer's input)``.
-        ``params`` defaults to the executor's working-frame params; pass
-        another axis's permuted params to build that axis's strip states.
+        ``params`` are the conv weights in one axis's working frame.
         """
-        if params is None:
-            params = self.params
         n = self.n_in  # full-walk spatial extent entering each layer
         P_cur, frag = 1, 1
         layers: List[Optional[PreparedLayer]] = [None] * len(self.net.layers)
@@ -848,44 +835,54 @@ class PlanExecutor:
                     n = n // layer.size
         return int(seg_row), int(halo_entry)
 
-    def _walk_below_input(self, states, x, S, *, capture: bool):
-        """Layers 1.. over a layer-0 output, optionally capturing halos.
+    def _walk_below_input(self, layers, states, x, S, *, capture, leads=None):
+        """Layers 1.. over a layer-0 output: the one walk of every step.
 
-        Applies each prepared layer in turn (ReLU after every conv but the
-        net's last — the whole-net rule ``apply_prepared_range`` states)
-        and, when ``capture`` (deep reuse on), records per layer the
-        trailing ``size - 1`` x-columns of its INPUT: the activation halos
-        the next x-patch's strip walk assembles from.  ``capture`` is a
-        trace-time constant — jitted callers that discard halos (deep
-        reuse off, the mixed-sweep fallback) must not materialize them as
-        jit outputs.  Returns ``(out, halos)``.
+        ``layers``/``states`` are the full walk's prepared layers (the full
+        and capture walks) or the strip walk's (``_build_strip_plan``).
+        Applies each layer in turn, ReLU by the whole-net rule
+        (``primitives.activate``).  ``leads`` (strip walk) holds the left
+        neighbour's cached activation halos: ``leads[i-1]`` is concatenated
+        in front of layer i's input, so each layer runs on ``new_x + size -
+        1`` columns.  When ``capture`` (deep reuse on), records per layer
+        the trailing ``size - 1`` x-columns of its input, after any lead is
+        concatenated: the halos the next x-patch's strip walk assembles
+        from.  ``capture`` is a trace-time constant — jitted callers that
+        discard halos (deep reuse off, the mixed-sweep fallback) must not
+        materialize them as jit outputs.  Returns ``(out, halos)``.
 
-        With ``fuse_os`` every eligible conv+pool pair dispatches to
+        With ``fuse_os`` every pair ``_fused_pairs`` names dispatches to
         ``fft_conv_pool_fused_halo``: the pool layer's input is never a
         separate walk step, yet its trailing columns still reach the halo
-        cache via the fused call's second output (bitwise-identical to the
-        unfused capture off the Pallas path).  Layers trace under the
-        named scopes ``apply_prepared_range`` uses.
+        cache via the fused call's second output, with the pool's lead
+        ``leads[i]`` assembled inside the call (bitwise-identical to the
+        unfused walk off the Pallas path).  Layers trace under the named
+        scopes ``apply_prepared_range`` uses.
         """
-        last_conv = max(
-            i for i, l in enumerate(self.net.layers) if l.kind == "conv"
-        )
         halos = []
         i = 1
-        while i < len(self.net.layers):
-            pl = self.compiled.layers[i]
+        while i < len(layers):
+            pl = layers[i]
+            size = self.net.layers[i].size
             fused = self.fuse_os and i in self._fused_pairs
             with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
+                if leads is not None:
+                    lead = _unflat_halo(leads[i - 1], x.shape[3:])
+                    x = jnp.concatenate([lead, x], axis=2)
                 if capture:
-                    h = self.net.layers[i].size - 1
-                    halos.append(_flat_halo(x[:, :, -h:]))
+                    halos.append(_flat_halo(x[:, :, -(size - 1):]))
                 if fused:
-                    nxt = self.compiled.layers[i + 1]
+                    nxt = layers[i + 1]
+                    pool_lead = None
+                    if leads is not None:
+                        pool_lead = _unflat_halo(
+                            leads[i], tuple(n - size + 1 for n in x.shape[3:])
+                        )
                     x, pool_halo = fft_conv_pool_fused_halo(
                         x, states[i]["W"], states[i]["b"],
                         fft_shape=pl.fft_shape, k=pl.kernel_size,
                         p=nxt.pool_size, halo_cols=nxt.pool_size - 1,
-                        use_pallas=self.use_pallas,
+                        lead=pool_lead, use_pallas=self.use_pallas,
                         fprime_chunk=pl.fprime_chunk,
                     )
                     if capture:
@@ -895,8 +892,7 @@ class PlanExecutor:
                 x = resolve_primitive(pl).apply(
                     pl, x, states[i], use_pallas=self.use_pallas
                 )
-                if pl.kind == "conv" and i != last_conv:
-                    x = jax.nn.relu(x)
+                x = activate(self.net, i, x)
             i += 1
         if self.uses_mpf:
             with jax.named_scope("recombine"):
@@ -912,23 +908,33 @@ class PlanExecutor:
         (empty halos unless ``capture``; see ``_walk_below_input``).
         """
         pl0 = self.compiled.layers[0]
-        last_conv = max(
-            i for i, l in enumerate(self.net.layers) if l.kind == "conv"
-        )
         with jax.named_scope("os_walk"):
             with jax.named_scope("L0.overlap_save"):
                 x = os_mod.os_apply_from_spectra(
                     F, states[0]["W"], states[0]["b"], pl0.os_spec,
                     use_pallas=self.use_pallas,
                 )
-                if last_conv != 0:
-                    x = jax.nn.relu(x)
-            return self._walk_below_input(states, x, F.shape[0], capture=capture)
+                x = activate(self.net, 0, x)
+            return self._walk_below_input(
+                self.compiled.layers, states, x, F.shape[0], capture=capture
+            )
 
-    def _assemble_spectra(self, Fm, parents, pattern, rows_per_patch):
-        rows = [Fm[j] if p < 0 else parents[p][j] for p, j in pattern]
-        S = len(pattern) // rows_per_patch
-        return jnp.stack(rows).reshape((S, rows_per_patch) + rows[0].shape)
+    def _layer0_spectra(self, vol, starts, parents, pattern, rows_per_patch):
+        """Both step programs' layer-0 prologue: ``(Fm, F)``.
+
+        ``Fm`` holds the miss spectra sliced from ``vol`` at ``starts``
+        (None without misses); ``F`` (S, rows_per_patch, f, ña, ñb, ñc)
+        stacks the rows ``pattern`` names, from ``Fm`` or ``parents``.
+        """
+        spec0 = self.compiled.layers[0].os_spec
+        with jax.named_scope("L0.spectra"):
+            Fm = None
+            if starts is not None:
+                Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
+            rows = [Fm[j] if p < 0 else parents[p][j] for p, j in pattern]
+            S = len(pattern) // rows_per_patch
+            F = jnp.stack(rows).reshape((S, rows_per_patch) + rows[0].shape)
+        return Fm, F
 
     def _os_step(self, states, vol, starts, parents, *, pattern):
         """ONE jitted call per full-path patch batch: miss FFTs + assembly
@@ -945,13 +951,9 @@ class PlanExecutor:
         cache can serve them to the next x-row, the halos so the deep
         activation cache can serve the next x-patch's strip walk.
         """
-        spec0 = self.compiled.layers[0].os_spec
+        n_seg = self.compiled.layers[0].os_spec.n_segments
         with jax.named_scope("os_step"):
-            with jax.named_scope("L0.spectra"):
-                Fm = None
-                if starts is not None:
-                    Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
-                F_all = self._assemble_spectra(Fm, parents, pattern, spec0.n_segments)
+            Fm, F_all = self._layer0_spectra(vol, starts, parents, pattern, n_seg)
             out, halos = self._os_walk(states, F_all, capture=self.deep_reuse)
         return out, Fm, halos
 
@@ -970,65 +972,19 @@ class PlanExecutor:
         miss spectra, and the batch's own trailing halos for the cache.
         """
         spec0 = self.compiled.layers[0].os_spec
-        last_conv = max(
-            i for i, l in enumerate(self.net.layers) if l.kind == "conv"
-        )
         with jax.named_scope("os_strip_step"):
-            with jax.named_scope("L0.spectra"):
-                Fm = None
-                if starts is not None:
-                    Fm = os_mod.slice_segment_spectra(vol, starts, spec0, self.extent)
-                F = self._assemble_spectra(Fm, parents, pattern, self._q_strip)
-            S = F.shape[0]
+            Fm, F = self._layer0_spectra(vol, starts, parents, pattern, self._q_strip)
             with jax.named_scope("L0.overlap_save"):
                 x = os_mod.os_apply_tail_from_spectra(
                     F, states[0]["W"], states[0]["b"], spec0, self.core,
                     use_pallas=self.use_pallas,
                 )
-                if last_conv != 0:
-                    x = jax.nn.relu(x)
-            new_halos = []
-            i = 1
-            while i < len(self.net.layers):
-                pl = self._strip_layers[i]
-                h, _ = self._strip_info[i]
-                fused = self.fuse_os and i in self._fused_pairs
-                with jax.named_scope(f"L{i}.{pl.prim}" + ("_mpf" if fused else "")):
-                    lead = _unflat_halo(halos[i - 1], x.shape[3:])
-                    x = jnp.concatenate([lead, x], axis=2)
-                    new_halos.append(_flat_halo(x[:, :, -h:]))
-                    if fused:
-                        # fused pair: the pool layer's input is the cached
-                        # lead halo ``halos[i]`` + the conv's ReLU output —
-                        # assembled INSIDE the fused call, which returns its
-                        # trailing ``strip_info[i+1]`` columns as the
-                        # pool-input halo
-                        nxt = self._strip_layers[i + 1]
-                        h_pool, _ = self._strip_info[i + 1]
-                        x, pool_halo = fft_conv_pool_fused_halo(
-                            x, strip_states[i]["W"], strip_states[i]["b"],
-                            fft_shape=pl.fft_shape, k=pl.kernel_size,
-                            p=nxt.pool_size, halo_cols=h_pool,
-                            lead=_unflat_halo(
-                                halos[i],
-                                tuple(n - self.net.layers[i].size + 1 for n in x.shape[3:]),
-                            ),
-                            use_pallas=self.use_pallas,
-                            fprime_chunk=pl.fprime_chunk,
-                        )
-                        new_halos.append(_flat_halo(pool_halo))
-                        i += 2
-                        continue
-                    x = resolve_primitive(pl).apply(
-                        pl, x, strip_states[i], use_pallas=self.use_pallas
-                    )
-                    if pl.kind == "conv" and i != last_conv:
-                        x = jax.nn.relu(x)
-                i += 1
-            if self.uses_mpf:
-                with jax.named_scope("recombine"):
-                    x = recombine_fragments(x, list(self.compiled.mpf_pools), S)
-        return x, Fm, tuple(new_halos)
+                x = activate(self.net, 0, x)
+            x, new_halos = self._walk_below_input(
+                self._strip_layers, strip_states, x, F.shape[0],
+                capture=True, leads=halos,
+            )
+        return x, Fm, new_halos
 
     def _run_os_batch(self, meta) -> np.ndarray:
         """Patch batch with layer-0 segment spectra served from the cache.
@@ -1120,6 +1076,28 @@ class PlanExecutor:
                 groups.append((rows, strip))
         return groups, halo_cache
 
+    def _resolve_segments(self, cache, keys, misses) -> List:
+        """Map a patch's segment keys to sweep-cache entries.
+
+        A key not in ``cache`` becomes a ``_PendingMiss`` for the next row
+        of ``misses`` (which the key is appended to); the marker, filed in
+        the cache, also dedups a key repeated later in the same batch
+        (bucketed tail repeats).  Counts hits, misses and the MAD+inverse
+        passes the keys cost.  Returns one entry per key.
+        """
+        entries = []
+        for key in keys:
+            F = cache.get(key)
+            if F is None:
+                F = cache[key] = _PendingMiss(len(misses))
+                misses.append(key)
+                self._os_misses += 1
+            else:
+                self._os_hits += 1
+            entries.append(F)
+        self._os_mad_segments += len(keys)
+        return entries
+
     def _run_os_group(self, token, metas, strip: bool):
         """Resolve + run one homogeneous (full or strip) patch group.
 
@@ -1130,31 +1108,20 @@ class PlanExecutor:
         device: the caller fetches ``out`` once every group is dispatched.
         """
         with obs.span("executor.resolve"):
-            spec0 = self.compiled.layers[0].os_spec
+            n_seg = self.compiled.layers[0].os_spec.n_segments
             cache = self._sweeps[token]
             # the sweep scope's axis picks the state pytrees (working-frame
             # weights + kernel spectra); the jitted step programs are shared
             states, strip_states = self._states_for_axis(
                 self._sweep_axes.get(token, self.sweep_axis)
             )
-            n_seg = spec0.n_segments
             q = self._q_strip if strip else n_seg
             misses: List[Tuple[int, int, int]] = []
             pattern: List[Tuple[int, int]] = []
             parents: List = []
             parent_pos: Dict[int, int] = {}
             for _, keys, _start in metas:
-                for key in keys[n_seg - q :] if strip else keys:
-                    F = cache.get(key)
-                    if F is None:
-                        # the pending marker in the cache also dedups repeated
-                        # keys within this group (bucketed tail repeats)
-                        F = _PendingMiss(len(misses))
-                        cache[key] = F
-                        misses.append(key)
-                        self._os_misses += 1
-                    else:
-                        self._os_hits += 1
+                for F in self._resolve_segments(cache, keys[n_seg - q :], misses):
                     if isinstance(F, _PendingMiss):
                         pattern.append((-1, F.idx))
                     else:
@@ -1163,14 +1130,6 @@ class PlanExecutor:
                             pos = parent_pos[id(F.parent)] = len(parents)
                             parents.append(F.parent)
                         pattern.append((pos, F.idx))
-            self._os_mad_segments += len(pattern)
-            if self.use_pallas:
-                # on the Pallas path every MAD+inverse segment runs through the
-                # fused os_segment kernel (one pallas_call: MAD, DC-bin bias,
-                # inverse, crop) — same count, so predictions stay exact
-                self._os_fused_segments += len(pattern)
-            if self.fuse_os:
-                self._fused_pair_calls += len(metas) * len(self._fused_pairs)
             if self.streaming:
                 # the group is one x-plane (plane-capped chunks / per-plane
                 # sub-groups): its segments all live in the staged slab
@@ -1185,7 +1144,6 @@ class PlanExecutor:
             starts = (
                 jnp.asarray(np.asarray(misses, np.int32) - off) if misses else None
             )
-            halos_in = None
             if strip:
                 halos_in = tuple(
                     jnp.concatenate(
@@ -1193,28 +1151,21 @@ class PlanExecutor:
                     )
                     for pos in range(len(self.net.layers) - 1)
                 )
+                step = self._jit_os_strip_step
+                step_args = (states, strip_states, vol, starts, tuple(parents), halos_in)
+            else:
+                step = self._jit_os_step
+                step_args = (states, vol, starts, tuple(parents))
+        self._record_trace(
+            ("strip" if strip else "full", tuple(pattern),
+             None if starts is None else len(misses), vol.shape, len(parents))
+        )
+        with obs.span("executor.dispatch"):
+            out, F_m, halos = step(*step_args, pattern=tuple(pattern))
         if strip:
-            self._record_trace(
-                ("strip", tuple(pattern), None if starts is None else len(misses),
-                 vol.shape, len(parents))
-            )
-            with obs.span("executor.dispatch"):
-                out, F_m, halos = self._jit_os_strip_step(
-                    states, strip_states, vol,
-                    starts, tuple(parents), halos_in, pattern=tuple(pattern),
-                )
             self._deep_strips += len(metas)
             self._conv_flops += len(metas) * self._strip_conv_flops
         else:
-            self._record_trace(
-                ("full", tuple(pattern), None if starts is None else len(misses),
-                 vol.shape, len(parents))
-            )
-            with obs.span("executor.dispatch"):
-                out, F_m, halos = self._jit_os_step(
-                    states, vol,
-                    starts, tuple(parents), pattern=tuple(pattern),
-                )
             self._deep_fulls += len(metas)
             self._conv_flops += len(metas) * self._full_conv_flops
         # the ledger's transient sample: group output + miss spectra +
@@ -1288,24 +1239,10 @@ class PlanExecutor:
             for token, keys, start in meta:
                 cache = self._sweeps.setdefault(token, {})
                 self._evict_left_of(token, start[0])
-                per_seg = []
-                for key in keys:
-                    F = cache.get(key)
-                    if F is None:
-                        misses = miss_keys.setdefault(token, [])
-                        F = _PendingMiss(len(misses))
-                        cache[key] = F
-                        misses.append(key)
-                        self._os_misses += 1
-                    else:
-                        self._os_hits += 1
-                    per_seg.append((key, F))
-                slots.append(per_seg)
-                self._os_mad_segments += spec0.n_segments
-                if self.use_pallas:
-                    self._os_fused_segments += spec0.n_segments
-                if self.fuse_os:
-                    self._fused_pair_calls += len(self._fused_pairs)
+                misses = miss_keys.get(token, [])
+                slots.append(list(zip(keys, self._resolve_segments(cache, keys, misses))))
+                if misses:
+                    miss_keys[token] = misses
                 self._deep_fulls += 1
             for token, keys_m in miss_keys.items():
                 # pad the miss count to a power of two so the distinct compiled
@@ -1469,7 +1406,6 @@ class PlanExecutor:
 
         self._os_misses = self._os_hits = self._os_mad_segments = 0
         self._deep_strips = self._deep_fulls = self._conv_flops = 0
-        self._fused_pair_calls = self._os_fused_segments = 0
         self._ledger.begin_run()  # peak scoped to this sweep
         t0 = time.perf_counter()
         # the sweep's device upload is real per-volume work the other
@@ -1506,11 +1442,15 @@ class PlanExecutor:
             "waste_fraction": tiling.waste_fraction,
             **self.counter_stats(),
             # fused-epilogue accounting: conv+pool pairs the halo-emitting
-            # fused epilogue served (``fuse_os``; (strips+fulls) × eligible
-            # pairs), and segments run through the fused Pallas segment
-            # kernel (== os_mad_segments on the Pallas path, else 0)
-            "fused_pair_calls": self._fused_pair_calls,
-            "os_fused_segments": self._os_fused_segments,
+            # fused epilogue served (``fuse_os``: every patch walk runs each
+            # eligible pair), and segments run through the fused Pallas
+            # os_segment kernel (one pallas_call: MAD, DC-bin bias, inverse,
+            # crop), which on the Pallas path runs every MAD+inverse pass
+            "fused_pair_calls": (
+                (self._deep_strips + self._deep_fulls) * len(self._fused_pairs)
+                if self.fuse_os else 0
+            ),
+            "os_fused_segments": self._os_mad_segments if self.use_pallas else 0,
             # distinct jit specializations dispatched so far (cumulative
             # over the executor's lifetime — serving watches this to see
             # shape-bucketing suppress per-request retraces)

@@ -16,7 +16,7 @@ def test_readme_and_architecture_doc_exist():
     readme = (REPO / "README.md").read_text()
     arch = (REPO / "docs" / "architecture.md").read_text()
     # README covers the quickstart + package map the issue asks for
-    for needle in ("pytest", "volume_throughput", "core", "volume", "serving",
+    for needle in ("pytest", "bench/run.py", "core", "volume", "serving",
                    "benchmarks", "docs/architecture.md"):
         assert needle in readme, needle
     # architecture doc documents the plan->execution contract + recipe
@@ -55,7 +55,7 @@ def test_module_docstrings_state_patch_invariants():
 def test_example_commands_in_readme_are_runnable():
     """Quickstart commands reference real files."""
     readme = (REPO / "README.md").read_text()
-    for path in ("benchmarks/volume_throughput.py", "benchmarks/table5_throughput.py",
+    for path in ("bench/run.py", "benchmarks/table5_throughput.py",
                  "tests/_hypothesis_compat.py"):
         assert path in readme
         assert os.path.exists(REPO / path), path

@@ -226,3 +226,51 @@ def test_fft_cached_cost_drops_kernel_weight_bytes():
     assert cached.flops < task.flops
     assert cached.hbm_bytes == task.hbm_bytes - fp * f * k**3 * cost_model.F32
     assert cached.peak_bytes == task.peak_bytes  # spectra residency still paid
+
+
+# -- the walk rules every walk shares ------------------------------------------
+
+C3, C2, POOL = L("conv", 3, 4), L("conv", 2, 4), L("pool", 2)
+
+
+def _prepared(net, prims):
+    """Metadata-only PreparedLayers: all the walk rules read."""
+    return tuple(
+        primitives.PreparedLayer(i, l.kind, p)
+        for i, (l, p) in enumerate(zip(net.layers, prims))
+    )
+
+
+@pytest.mark.parametrize(
+    "layers, prims, starts",
+    [
+        # an fft_cached conv followed by its mpf pool is chosen
+        ((C2, POOL, C3, POOL, C3, C3),
+         ["fft_cached", "mpf", "fft_cached", "mpf", "direct", "direct"], (0, 2)),
+        # the net's last conv is never chosen, even before an mpf pool
+        ((C2, POOL, C3, POOL), ["fft_cached", "mpf", "fft_cached", "mpf"], (0,)),
+        # a direct conv before an mpf pool is not chosen
+        ((C2, POOL, C3, POOL, C3), ["direct", "mpf", "direct", "mpf", "fft_cached"], ()),
+        # an fft_cached conv followed by a conv is not chosen
+        ((C3, C3, POOL, C3), ["fft_cached", "direct", "mpf", "fft_cached"], ()),
+        # a plain pool does not pair
+        ((C3, POOL, C3), ["fft_cached", "pool", "fft_cached"], ()),
+    ],
+    ids=["eligible", "last-conv", "direct-conv", "conv-after", "plain-pool"],
+)
+def test_fused_pair_rule(layers, prims, starts):
+    net = ConvNetConfig("rule-toy", 1, layers)
+    prepared = _prepared(net, prims)
+    assert primitives.fused_pair_starts(net, prepared) == starts
+    # the executor's walks below layer 0 see the pairs that start there
+    assert primitives.fused_pair_starts(net, prepared[1:]) == tuple(
+        i for i in starts if i >= 1
+    )
+
+
+@pytest.mark.parametrize("i, relu", [(0, True), (1, False), (2, True), (3, False), (4, False)])
+def test_activation_rule_relu_after_every_conv_but_the_last(i, relu):
+    """NET is conv, pool, conv, pool, conv: ReLU after convs 0 and 2 only."""
+    assert primitives.last_conv_index(NET) == 4
+    y = np.asarray(primitives.activate(NET, i, jnp.asarray([-1.0, 2.0])))
+    np.testing.assert_array_equal(y, [0.0, 2.0] if relu else [-1.0, 2.0])
